@@ -38,7 +38,8 @@ Acceptance (the tentpole's headline):
 A second segment runs the real ``ClusterPlane`` (mesh-sharded engines,
 shared ledger, merged trace) end-to-end on a smoke model — on CI's
 forced 8-device host platform this exercises true multi-device
-placement; on one CPU device it degrades to shared 1-device meshes.
+placement; a host with one device runs one replica, as replicas never
+share a device.
 """
 from __future__ import annotations
 
@@ -237,8 +238,9 @@ def run_plane_smoke(registry=None) -> List[Tuple[str, float, str]]:
 
     cfg = get_smoke_config("llama3-8b")
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    # a replica needs a device of its own
     plane = ClusterPlane(
-        cfg, params, n_replicas=2,
+        cfg, params, n_replicas=min(2, len(jax.devices())),
         serving=ServingConfig(block_tokens=8, max_batch=2,
                               max_context=32, policy="tiering08"))
     rs = np.random.RandomState(0)

@@ -96,11 +96,8 @@ def write_json(path: str, results, smoke: bool, wall_s: float,
             "wall_s": wall_s,
         },
     }
-    try:
-        import jax
-        payload["jax"] = jax.__version__
-    except Exception:                                  # pragma: no cover
-        payload["jax"] = None
+    import jax
+    payload["jax"] = jax.__version__
     with open(path, "w") as f:
         json.dump(payload, f, indent=2, sort_keys=True)
         f.write("\n")
@@ -136,6 +133,8 @@ def main(argv=None) -> None:
               f"available: {', '.join(MODULES)}", file=sys.stderr)
         sys.exit(2)
 
+    from repro.launch.compile_cache import place_compile_cache
+    place_compile_cache()
     only = args.names or MODULES
     failures = 0
     results = []

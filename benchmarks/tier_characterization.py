@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import assign_streams, paper_system
-from repro.core.tiered_array import _device_sharding
+from repro.core.tiered_array import sharding_for_kind
 
 
 def fig2_latency_rows():
@@ -62,30 +62,27 @@ def measured_host_tier_rows(n_mb: int = 64, iters: int = 5):
     """Measured device<->host-kind transfer time on this machine."""
     rows = []
     x = jnp.zeros((n_mb * 1024 * 1024 // 4,), jnp.float32)
-    x = jax.device_put(x, _device_sharding("device"))
+    x = jax.device_put(x, sharding_for_kind("device"))
     jax.block_until_ready(x)
     for kind in ("pinned_host", "unpinned_host"):
-        try:
-            # device -> kind
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                y = jax.device_put(x, _device_sharding(kind))
-                jax.block_until_ready(y)
-            dt = (time.perf_counter() - t0) / iters
-            rows.append((f"measured.dev_to_{kind}.{n_mb}MB",
-                         dt * 1e6, "us"))
-            rows.append((f"measured.dev_to_{kind}.bw",
-                         n_mb / 1024 / dt, "GB/s"))
-            # kind -> device
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                z = jax.device_put(y, _device_sharding("device"))
-                jax.block_until_ready(z)
-            dt = (time.perf_counter() - t0) / iters
-            rows.append((f"measured.{kind}_to_dev.bw",
-                         n_mb / 1024 / dt, "GB/s"))
-        except Exception as e:  # pragma: no cover
-            rows.append((f"measured.{kind}.error", 0.0, str(e)[:40]))
+        # device -> kind
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            y = jax.device_put(x, sharding_for_kind(kind))
+            jax.block_until_ready(y)
+        dt = (time.perf_counter() - t0) / iters
+        rows.append((f"measured.dev_to_{kind}.{n_mb}MB",
+                     dt * 1e6, "us"))
+        rows.append((f"measured.dev_to_{kind}.bw",
+                     n_mb / 1024 / dt, "GB/s"))
+        # kind -> device
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            z = jax.device_put(y, sharding_for_kind("device"))
+            jax.block_until_ready(z)
+        dt = (time.perf_counter() - t0) / iters
+        rows.append((f"measured.{kind}_to_dev.bw",
+                     n_mb / 1024 / dt, "GB/s"))
     return rows
 
 

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import tpu_v5e_tiers
-from repro.core.tiered_array import _device_sharding
+from repro.core.tiered_array import sharding_for_kind
 
 
 def measured_rows():
@@ -22,11 +22,11 @@ def measured_rows():
         n = size_mb * 1024 * 1024 // 4
         base = jnp.zeros((n,), jnp.float32)
         for kind in ("pinned_host", "unpinned_host"):
-            x = jax.device_put(base, _device_sharding(kind))
+            x = jax.device_put(base, sharding_for_kind(kind))
             jax.block_until_ready(x)
             t0 = time.perf_counter()
             for _ in range(5):
-                y = jax.device_put(x, _device_sharding("device"))
+                y = jax.device_put(x, sharding_for_kind("device"))
                 jax.block_until_ready(y)
             dt = (time.perf_counter() - t0) / 5
             rows.append((f"fig5.{kind}_to_device.{label}.bw",
@@ -34,11 +34,11 @@ def measured_rows():
     # Fig. 6: 64-byte latency analogue
     tiny = jnp.zeros((16,), jnp.float32)
     for kind in ("pinned_host", "unpinned_host"):
-        x = jax.device_put(tiny, _device_sharding(kind))
+        x = jax.device_put(tiny, sharding_for_kind(kind))
         jax.block_until_ready(x)
         t0 = time.perf_counter()
         for _ in range(200):
-            y = jax.device_put(x, _device_sharding("device"))
+            y = jax.device_put(x, sharding_for_kind("device"))
             jax.block_until_ready(y)
         dt = (time.perf_counter() - t0) / 200
         rows.append((f"fig6.{kind}_to_device.64B.latency",

@@ -29,7 +29,6 @@ __all__ = [
     "current_axis_mapping",
     "is_pattern",
     "replica_meshes",
-    "replica_shard_map",
     "reset_bare_key_warning",
     "shard_lm_params",
 ]
@@ -39,7 +38,6 @@ _LAZY = {
     "axis_mapping": "sharding",
     "current_axis_mapping": "sharding",
     "replica_meshes": "sharding",
-    "replica_shard_map": "sharding",
     "shard_lm_params": "sharding",
     "Replica": "replica",
     "ClusterPlane": "plane",

@@ -19,6 +19,7 @@ from typing import Callable, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ..core.tiered_array import sharding_for_kind
 from ..serving import ServingConfig, ServingEngine
 from .namespace import Namespace
 from .sharding import current_axis_mapping, shard_lm_params
@@ -28,15 +29,13 @@ __all__ = ["Replica"]
 
 def _mesh_pool_sharding(mesh: Mesh) -> Callable[[str], object]:
     """Pool-block placement on the replica mesh: replicated over its
-    devices, on the requested memory kind when the platform exposes it
-    (same degradation rule as ``sharding_for_kind``)."""
+    devices, on the requested memory kind.  Raises on a kind the
+    devices lack, as ``sharding_for_kind`` does."""
     dev = mesh.devices.flat[0]
-    kinds = {m.kind for m in dev.addressable_memories()}
-    default = dev.default_memory().kind
 
     def fn(kind: str):
-        mk = kind if kind in kinds else default
-        return NamedSharding(mesh, PartitionSpec(), memory_kind=mk)
+        sharding_for_kind(kind, dev)       # raises on a missing kind
+        return NamedSharding(mesh, PartitionSpec(), memory_kind=kind)
 
     return fn
 
@@ -91,6 +90,27 @@ class Replica:
                             self.engine.pool.slow_kind)
             self.engine.topo = topo
         self.testbed = testbed
+        self.check_placement()
+
+    def check_placement(self) -> None:
+        """Raise if any array the replica holds (params, KV blocks,
+        pooled stores) lives on a device outside its mesh — on chip 0,
+        say, where every uncommitted array lands by default."""
+        if self.mesh is None:
+            return
+        own = set(self.mesh.devices.flat)
+        pool = self.engine.pool
+        arrays = jax.tree.leaves(self.params) + [
+            a for b in pool.blocks for a in (b.k, b.v) if a is not None]
+        arrays += [a for a in (pool.k_store, pool.v_store)
+                   if a is not None]
+        for a in arrays:
+            stray = set(a.devices()) - own
+            if stray:
+                raise RuntimeError(
+                    f"replica {self.name}: an array of shape {a.shape} "
+                    f"lives on {sorted(map(str, stray))}, outside its "
+                    f"mesh {sorted(map(str, own))}")
 
     # -- the router's live signals ------------------------------------ #
     def fast_headroom_bytes(self) -> int:
@@ -110,7 +130,9 @@ class Replica:
                                   arrival_s=arrival_s, priority=priority)
 
     def run(self, max_iterations: int = 10_000):
-        return self.engine.run(max_iterations=max_iterations)
+        report = self.engine.run(max_iterations=max_iterations)
+        self.check_placement()
+        return report
 
     def __repr__(self) -> str:
         nd = self.mesh.devices.size if self.mesh is not None else 0

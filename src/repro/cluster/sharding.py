@@ -1,24 +1,14 @@
 """Named-axis sharding for the multi-host serving plane.
 
-Two idioms from the ecosystem, adapted to the repo's plain-pytree
-models:
-
-* **axis mapping** (haliax): model code names *logical* axes
-  ("embed", "vocab", "experts"); a thread-local :class:`AxisMapping`
-  resolves them to *physical* mesh axes at placement time, so the
-  same model runs replicated, tensor-sharded, or expert-sharded by
-  swapping one context, never editing model code.
-* **shard_map adapter** (equinox ``filter_shard_map``): a thin
-  wrapper that partitions array args over the mesh and leaves
-  non-arrays alone, version-adaptive across the
-  ``jax.experimental.shard_map`` -> ``jax.shard_map`` migration.
+The idiom, from haliax, adapted to the repo's plain-pytree models:
+model code names *logical* axes ("embed", "vocab", "experts"); a
+thread-local :class:`AxisMapping` resolves them to *physical* mesh axes
+at placement time, so the same model runs replicated, tensor-sharded,
+or expert-sharded by swapping one context, never editing model code.
 
 The meshes themselves come from :func:`replica_meshes`, which
-partitions the process's devices into per-replica groups.  Under the
-tier-1 test environment (one CPU device) every replica degrades to a
-1-device mesh sharing that device — placement semantics are exercised,
-parallel speed is not.  CI's cluster-smoke step forces 8 host-platform
-devices to exercise real multi-device placement.
+partitions the process's devices into disjoint per-replica groups; a
+replica never shares a device with another.
 """
 from __future__ import annotations
 
@@ -31,13 +21,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:                                      # jax >= 0.4.35 path
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:                       # pragma: no cover - newer jax
-    _shard_map = getattr(jax, "shard_map", None)
-
 __all__ = ["AxisMapping", "axis_mapping", "current_axis_mapping",
-           "replica_meshes", "replica_shard_map", "shard_lm_params"]
+           "replica_meshes", "shard_lm_params"]
 
 # logical axis names the LM param tree exposes, by leaf dimension:
 # embed/lm_head are (vocab, d_model); per-unit stacks lead with "unit"
@@ -110,26 +95,26 @@ def axis_mapping(mapping: "AxisMapping | Mapping[str, Optional[str]]"):
 def replica_meshes(n_replicas: int,
                    axis_name: str = MODEL_AXIS,
                    devices: Optional[List] = None) -> List[Mesh]:
-    """Partition the process's devices into ``n_replicas`` 1-D meshes.
+    """Partition the process's devices into ``n_replicas`` disjoint
+    1-D meshes.
 
     With ``d`` devices and ``n`` replicas each mesh gets ``d // n``
-    devices (remainder unused, keeping replicas symmetric).  With
-    fewer devices than replicas, replicas *share* devices round-robin
-    — 1-device meshes that keep every placement code path alive on the
-    single-CPU tier-1 environment.
+    devices (remainder unused, keeping replicas symmetric).  Fewer
+    devices than replicas, or a device listed twice, raises: replicas
+    sharing a chip would contend for its HBM and hide it.
     """
     if n_replicas < 1:
         raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
     devs = list(devices if devices is not None else jax.devices())
+    if len(set(devs)) != len(devs):
+        raise ValueError(f"a device is listed twice in {devs}: two "
+                         f"replicas would share it")
+    if len(devs) < n_replicas:
+        raise ValueError(f"{n_replicas} replicas need {n_replicas} "
+                         f"devices, found {len(devs)}")
     per = len(devs) // n_replicas
-    meshes = []
-    for r in range(n_replicas):
-        if per >= 1:
-            group = devs[r * per:(r + 1) * per]
-        else:
-            group = [devs[r % len(devs)]]
-        meshes.append(Mesh(np.array(group), (axis_name,)))
-    return meshes
+    return [Mesh(np.array(devs[r * per:(r + 1) * per]), (axis_name,))
+            for r in range(n_replicas)]
 
 
 def _leaf_logical_axes(path: Tuple[str, ...], ndim: int) -> List[Optional[str]]:
@@ -202,18 +187,3 @@ def shard_lm_params(params, mesh: Mesh,
         return flat[path]
 
     return rebuild(params)
-
-
-def replica_shard_map(fn, mesh: Mesh, in_specs, out_specs,
-                      check_rep: bool = False):
-    """``shard_map`` adapter: partition ``fn`` over a replica mesh.
-
-    Wraps whichever shard_map this jax exposes; ``check_rep=False``
-    because the serving kernels freely mix replicated scalars with
-    partitioned blocks.  Mirrors equinox's ``filter_shard_map`` shape:
-    specs may be prefix pytrees.
-    """
-    if _shard_map is None:           # pragma: no cover - ancient jax
-        raise RuntimeError("this jax exposes no shard_map")
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_rep)

@@ -41,36 +41,24 @@ TIER_TO_MEMORY_KIND = {
 }
 
 
-# Logical kinds the placement layer accepts.  On an accelerator host all
-# three are distinct physical memories; on a single-memory host (CPU CI)
-# they are *logical* tiers all backed by the device's default memory, so
-# placement bookkeeping (shares, bytes_on, fast_fraction) still works and
-# the same code places physically on TPU.
-LOGICAL_KINDS = ("device", "pinned_host", "unpinned_host")
-
-
-def physical_memory_kinds(device: Optional[jax.Device] = None) -> List[str]:
+def available_memory_kinds(device: Optional[jax.Device] = None
+                           ) -> List[str]:
+    """Memory kinds ``device`` (default: the first device) exposes."""
     device = device or jax.devices()[0]
-    return [m.kind for m in device.addressable_memories()]
+    return sorted(m.kind for m in device.addressable_memories())
 
 
 def sharding_for_kind(memory_kind: str,
                       device: Optional[jax.Device] = None):
-    """SingleDeviceSharding on `memory_kind`, degrading to the device's
-    default memory when the platform doesn't expose that kind."""
+    """SingleDeviceSharding on ``memory_kind``.  Raises when the device
+    has no such memory: a placement that silently landed in default
+    memory would hide a wrong tier."""
     device = device or jax.devices()[0]
-    if memory_kind not in physical_memory_kinds(device):
-        memory_kind = device.default_memory().kind
+    kinds = available_memory_kinds(device)
+    if memory_kind not in kinds:
+        raise ValueError(f"{device} has no memory kind {memory_kind!r} "
+                         f"(it has {kinds})")
     return jax.sharding.SingleDeviceSharding(device, memory_kind=memory_kind)
-
-
-_device_sharding = sharding_for_kind
-
-
-def available_memory_kinds() -> List[str]:
-    """Kinds accepted for placement: the logical tier set plus anything
-    extra the platform physically exposes."""
-    return sorted(set(LOGICAL_KINDS) | set(physical_memory_kinds()))
 
 
 @dataclasses.dataclass
@@ -130,13 +118,10 @@ class TieredArray:
         x = jnp.asarray(x)
         if x.ndim == 0:
             x = x[None]
-        kinds_avail = set(available_memory_kinds())
         spans = cls.plan_blocks(x.shape[0], shares, block_rows)
         blocks, kinds = [], []
         for a, b, kind in spans:
-            if kind not in kinds_avail:  # degrade gracefully off-host
-                kind = "device"
-            blk = jax.device_put(x[a:b], _device_sharding(kind))
+            blk = jax.device_put(x[a:b], sharding_for_kind(kind))
             blocks.append(blk)
             kinds.append(kind)
         return cls(blocks, kinds, tuple(x.shape), x.dtype)
@@ -160,7 +145,7 @@ class TieredArray:
         All block transfers are dispatched first (async), then concatenated:
         later DMAs overlap earlier concat work.
         """
-        dev = _device_sharding("device")
+        dev = sharding_for_kind("device")
         moved = [jax.device_put(b, dev) for b in self.blocks]  # async batch
         if len(moved) == 1:
             return moved[0].reshape(self.shape)
@@ -169,7 +154,7 @@ class TieredArray:
     def prefetch_blocks(self) -> Iterator[jax.Array]:
         """Double-buffered block stream: block i+1's DMA is in flight while
         block i is consumed (the ZeRO-Offload bucket pipeline)."""
-        dev = _device_sharding("device")
+        dev = sharding_for_kind("device")
         nxt = jax.device_put(self.blocks[0], dev)
         for i in range(len(self.blocks)):
             cur = nxt
@@ -184,7 +169,7 @@ class TieredArray:
         if self.kinds[i] == kind:
             return 0
         self.blocks[i] = jax.device_put(self.blocks[i],
-                                        _device_sharding(kind))
+                                        sharding_for_kind(kind))
         self.kinds[i] = kind
         per_row = self.nbytes // max(self.shape[0], 1)
         return self.blocks[i].shape[0] * per_row
@@ -197,7 +182,7 @@ class TieredArray:
         for b, kind in zip(self.blocks, self.kinds):
             stop = start + b.shape[0]
             out_blocks.append(
-                jax.device_put(x[start:stop], _device_sharding(kind)))
+                jax.device_put(x[start:stop], sharding_for_kind(kind)))
             start = stop
         return TieredArray(out_blocks, list(self.kinds), self.shape,
                            self.dtype)

@@ -9,7 +9,9 @@ heads so each KV head is read ONCE for its `rep` query heads (a GQA
 bandwidth optimization a naive repeat would forfeit).
 
 Grid: (B, nk) — kv blocks innermost and sequential, accumulators live in
-VMEM scratch.  kv_len masks the unwritten tail of the cache buffer.
+VMEM scratch.  kv_len masks the unwritten tail of the cache buffer; it
+rides the scalar-prefetch channel into SMEM, because Mosaic refuses a
+rank-1 VMEM block narrower than 128 lanes.
 """
 from __future__ import annotations
 
@@ -22,9 +24,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# the largest power-of-two kv block that fits v5e's scoped VMEM at GQA
+# widths (KV=4, hd=128): the (block_k, KV, hd) tiles pad KV up to a full
+# sublane tile, and block_k=256 runs out of VMEM there
+DEF_BLOCK_K = 128
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, block_k: int, rep: int,
                    scale: float):
     ik = pl.program_id(1)
@@ -36,7 +42,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = len_ref[0]
+    kv_len = len_ref[pl.program_id(0)]
     q = q_ref[0].astype(jnp.float32)             # (H, hd)  H = KV*rep
     k = k_ref[0].astype(jnp.float32)             # (block_k, KV, hd)
     v = v_ref[0].astype(jnp.float32)
@@ -68,8 +74,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def decode_attention(q, k_cache, v_cache, kv_len, *, block_k: int = 256,
-                     interpret: bool = True):
+def decode_attention(q, k_cache, v_cache, kv_len, *,
+                     block_k: int = DEF_BLOCK_K, interpret: bool):
     """q: (B, H, hd); caches: (B, S, KV, hd); kv_len: (B,) or scalar.
 
     Returns (B, H, hd).  S % block_k == 0 (cache buffers are padded)."""
@@ -82,21 +88,26 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, block_k: int = 256,
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
     kernel = functools.partial(_decode_kernel, block_k=block_k, rep=rep,
                                scale=scale)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, KV, hd), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, block_k, KV, hd), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1,), lambda b, j: (b,)),
+            pl.BlockSpec((1, H, hd), lambda b, j, lens: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, KV, hd),
+                         lambda b, j, lens: (b, j, 0, 0)),
+            pl.BlockSpec((1, block_k, KV, hd),
+                         lambda b, j, lens: (b, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, H, hd), lambda b, j, lens: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((KV, rep), jnp.float32),
             pltpu.VMEM((KV, rep), jnp.float32),
             pltpu.VMEM((KV, rep, hd), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=interpret,
-    )(q, k_cache, v_cache, kv_len)
+    )(kv_len, q, k_cache, v_cache)

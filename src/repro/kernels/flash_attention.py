@@ -71,7 +71,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "block_k", "interpret"))
 def flash_attention_bh(q, k, v, *, causal: bool = True,
                        block_q: int = 128, block_k: int = 128,
-                       interpret: bool = True):
+                       interpret: bool):
     """Flat-head flash attention.
 
     q: (BH, Sq, hd); k, v: (BH, Sk, hd).  Returns (BH, Sq, hd).
@@ -104,7 +104,7 @@ def flash_attention_bh(q, k, v, *, causal: bool = True,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool):
     """(B, Sq, H, hd) x (B, Sk, KV, hd) GQA wrapper around the kernel."""
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]

@@ -54,15 +54,14 @@ def _adam_kernel(master_ref, m_ref, v_ref, g_ref, lr_ref, hyp_ref,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def fused_adam_2d(master, m, v, g, lr, hyp, *,
                   block_rows: int = DEF_BLOCK_ROWS,
-                  interpret: bool = True):
+                  interpret: bool):
     """master/m/v: (R, LANES) fp32; g: (R, LANES) any float; lr: (1,);
     hyp: (6,) = [b1, b2, eps, wd, b1c, b2c]."""
     R = master.shape[0]
     blk = min(block_rows, R)
     grid = (-(-R // blk),)
     spec = pl.BlockSpec((blk, LANES), lambda i: (i, 0))
-    scal = pl.BlockSpec(memory_space=pl.ANY) if False else \
-        pl.BlockSpec((1,), lambda i: (0,))
+    scal = pl.BlockSpec((1,), lambda i: (0,))
     hyp_spec = pl.BlockSpec((6,), lambda i: (0,))
     out_shape = [jax.ShapeDtypeStruct((R, LANES), jnp.float32)] * 3
     return pl.pallas_call(
@@ -79,7 +78,7 @@ def fused_adam(master: jax.Array, m: jax.Array, v: jax.Array,
                g: jax.Array, *, lr: float, b1: float, b2: float,
                eps: float, wd: float, b1c, b2c,
                block_rows: int = DEF_BLOCK_ROWS,
-               interpret: bool = True
+               interpret: bool
                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Arbitrary-shape wrapper: pads/reshapes to (R, 128) lanes."""
     shape = master.shape

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the kernel body executes as pure
-JAX on CPU — exactly how the test suite validates against ref.py); on a
-TPU backend the same calls compile to Mosaic.
+The backend picks the mode: on the CPU the kernel body runs in
+interpret mode (pure JAX — how the tests check it against ref.py); on a
+TPU the same calls compile to Mosaic.  Any other backend is an error,
+never a silent interpreter run.
 """
 from __future__ import annotations
 
@@ -17,7 +18,11 @@ from . import tiered_gather as _tg
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run on tpu (Mosaic) or cpu "
+                           f"(interpret mode), not on {backend!r}")
+    return backend == "cpu"
 
 
 def fused_adam(master, m, v, g, *, lr, b1, b2, eps, wd, b1c, b2c,
@@ -35,8 +40,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                                block_k=block_k, interpret=_interpret())
 
 
-def decode_attention(q, k_cache, v_cache, kv_len, *, block_k: int = 256
-                     ) -> jax.Array:
+def decode_attention(q, k_cache, v_cache, kv_len, *,
+                     block_k: int = _dec.DEF_BLOCK_K) -> jax.Array:
     return _dec.decode_attention(q, k_cache, v_cache, kv_len,
                                  block_k=block_k, interpret=_interpret())
 

@@ -32,8 +32,8 @@ Two kernels:
     the ``(B, k, d_model, d_ff)`` selection first — top_k/n_experts of
     the store copied per token *before* any FLOP.
 
-Both run under ``interpret=True`` off-TPU (CPU CI), like every kernel
-in this package.
+Both run under ``interpret=True`` on the CPU (the tests), like every
+kernel in this package, and compile to Mosaic on the TPU.
 """
 from __future__ import annotations
 
@@ -46,12 +46,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# F columns of an expert streamed per grid step: whole (D, F) expert
+# blocks at published MoE widths overflow v5e's scoped VMEM once
+# double-buffered
+BLOCK_F = 256
 
 
 # ---------------------------------------------------------------------- #
 # paged decode attention                                                  #
 # ---------------------------------------------------------------------- #
-def _paged_decode_kernel(tbl_ref, q_ref, k_ref, v_ref, len_ref,
+def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
                          knew_ref, vnew_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, block_tokens: int,
                          rep: int, scale: float):
@@ -65,7 +69,7 @@ def _paged_decode_kernel(tbl_ref, q_ref, k_ref, v_ref, len_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = len_ref[0]
+    kv_len = len_ref[b]
     q = q_ref[0].astype(jnp.float32)              # (H, hd)  H = KV*rep
     k = k_ref[0].astype(jnp.float32)              # (bt, KV, hd)
     v = v_ref[0].astype(jnp.float32)
@@ -111,7 +115,7 @@ def _paged_decode_kernel(tbl_ref, q_ref, k_ref, v_ref, len_ref,
 @functools.partial(jax.jit, static_argnames=("block_tokens", "interpret"))
 def paged_decode_attention(q, k_pool, v_pool, block_tbl, kv_len,
                            k_new, v_new, *, block_tokens: int,
-                           interpret: bool = True):
+                           interpret: bool):
     """Decode attention straight over the paged pool layout.
 
     q: (B, H, hd); k_pool/v_pool: (num_blocks, block_tokens, KV, hd) —
@@ -120,7 +124,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tbl, kv_len,
     they are masked by ``kv_len``); kv_len: (B,) tokens already cached;
     k_new/v_new: (B, KV, hd) — this step's token, attended at position
     ``kv_len`` without ever being staged.  Returns (B, H, hd) attention
-    over ``kv_len + 1`` positions.
+    over ``kv_len + 1`` positions.  The block table and ``kv_len`` both
+    ride the scalar-prefetch channel into SMEM.
     """
     B, H, hd = q.shape
     KV = k_pool.shape[2]
@@ -135,19 +140,19 @@ def paged_decode_attention(q, k_pool, v_pool, block_tbl, kv_len,
                                block_tokens=block_tokens, rep=rep,
                                scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, j, tbl: (b, 0, 0)),
+            pl.BlockSpec((1, H, hd), lambda b, j, tbl, lens: (b, 0, 0)),
             pl.BlockSpec((1, block_tokens, KV, hd),
-                         lambda b, j, tbl: (tbl[b, j], 0, 0, 0)),
+                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0, 0)),
             pl.BlockSpec((1, block_tokens, KV, hd),
-                         lambda b, j, tbl: (tbl[b, j], 0, 0, 0)),
-            pl.BlockSpec((1,), lambda b, j, tbl: (b,)),
-            pl.BlockSpec((1, KV, hd), lambda b, j, tbl: (b, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda b, j, tbl: (b, 0, 0)),
+                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, KV, hd), lambda b, j, tbl, lens: (b, 0, 0)),
+            pl.BlockSpec((1, KV, hd), lambda b, j, tbl, lens: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, j, tbl: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, hd),
+                               lambda b, j, tbl, lens: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((KV, rep), jnp.float32),
             pltpu.VMEM((KV, rep), jnp.float32),
@@ -159,66 +164,78 @@ def paged_decode_attention(q, k_pool, v_pool, block_tbl, kv_len,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=interpret,
-    )(block_tbl, q, k_pool, v_pool, kv_len, k_new, v_new)
+    )(block_tbl, kv_len, q, k_pool, v_pool, k_new, v_new)
 
 
 # ---------------------------------------------------------------------- #
 # fused expert FFN                                                        #
 # ---------------------------------------------------------------------- #
-def _expert_ffn_kernel(ids_ref, x_ref, wg_ref, wu_ref, wd_ref, wts_ref,
+def _expert_ffn_kernel(ids_ref, wts_ref, x_ref, wg_ref, wu_ref, wd_ref,
                        o_ref, acc_scr):
+    b = pl.program_id(0)
     k = pl.program_id(1)
-    K = pl.num_programs(1)
+    f = pl.program_id(2)
+    last = (k == pl.num_programs(1) - 1) & (f == pl.num_programs(2) - 1)
 
-    @pl.when(k == 0)
+    @pl.when((k == 0) & (f == 0))
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    x = x_ref[0].astype(jnp.float32)              # (D,)
-    wg = wg_ref[0].astype(jnp.float32)            # (D, F)
-    wu = wu_ref[0].astype(jnp.float32)
-    wd = wd_ref[0].astype(jnp.float32)            # (F, D)
-    w = wts_ref[0, k].astype(jnp.float32)
-    h = jax.nn.silu(x @ wg) * (x @ wu)            # (F,)
-    acc_scr[...] = acc_scr[...] + w * (h @ wd)
+    # the FFN is separable over F: silu(x @ wg[:, f]) * (x @ wu[:, f])
+    # only ever meets wd[f, :], so each F tile adds its share of the
+    # down projection to the accumulator
+    x = x_ref[0]                                  # (1, D)
+    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(wd_ref.dtype)  # (1, tf)
+    down = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32)
+    acc_scr[...] = acc_scr[...] + wts_ref[b, k] * down
 
-    @pl.when(k == K - 1)
+    @pl.when(last)
     def _finalize():
         o_ref[0] = acc_scr[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_expert_ffn(x, w_gate, w_up, w_down, expert_ids, expert_wts,
-                     *, interpret: bool = True):
+                     *, interpret: bool):
     """Top-k expert FFN gathered straight from the stacked expert store.
 
     x: (B, D); w_gate/w_up: (E, D, F); w_down: (E, F, D) — the
     tier-resident expert weight blocks; expert_ids: (B, K) int32 routed
     experts per token; expert_wts: (B, K) normalized router weights.
     Returns (B, D): sum_k w[b,k] * ffn_silu(x[b]; expert ids[b,k]).
-    Only the K routed experts' weights are read per token.
+    Only the K routed experts' weights are read per token,
+    ``min(F, BLOCK_F)`` columns of F at a time; that tile must divide F.
     """
     B, D = x.shape
     E, _, F = w_gate.shape
     K = expert_ids.shape[1]
+    tf = min(F, BLOCK_F)
+    if F % tf:
+        raise ValueError(f"d_ff {F} is not a multiple of BLOCK_F {BLOCK_F}")
     expert_ids = expert_ids.astype(jnp.int32)
     expert_wts = expert_wts.astype(jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, K),
+        num_scalar_prefetch=2,
+        grid=(B, K, F // tf),
         in_specs=[
-            pl.BlockSpec((1, D), lambda b, k, ids: (b, 0)),
-            pl.BlockSpec((1, D, F), lambda b, k, ids: (ids[b, k], 0, 0)),
-            pl.BlockSpec((1, D, F), lambda b, k, ids: (ids[b, k], 0, 0)),
-            pl.BlockSpec((1, F, D), lambda b, k, ids: (ids[b, k], 0, 0)),
-            pl.BlockSpec((1, K), lambda b, k, ids: (b, 0)),
+            pl.BlockSpec((1, 1, D), lambda b, k, f, ids, wts: (b, 0, 0)),
+            pl.BlockSpec((1, D, tf),
+                         lambda b, k, f, ids, wts: (ids[b, k], 0, f)),
+            pl.BlockSpec((1, D, tf),
+                         lambda b, k, f, ids, wts: (ids[b, k], 0, f)),
+            pl.BlockSpec((1, tf, D),
+                         lambda b, k, f, ids, wts: (ids[b, k], f, 0)),
         ],
-        out_specs=pl.BlockSpec((1, D), lambda b, k, ids: (b, 0)),
-        scratch_shapes=[pltpu.VMEM((D,), jnp.float32)],
+        out_specs=pl.BlockSpec((1, 1, D),
+                               lambda b, k, f, ids, wts: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((1, D), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _expert_ffn_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, D), x.dtype),
         interpret=interpret,
-    )(expert_ids, x, w_gate, w_up, w_down, expert_wts)
+    )(expert_ids, expert_wts, x[:, None], w_gate, w_up, w_down)
+    return out[:, 0]

@@ -32,10 +32,12 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from ..configs import get_config, get_smoke_config
 from ..models import lm
 from ..offload.serve_engine import FlexGenEngine, ServeConfig
+from .compile_cache import place_compile_cache
 
 
 def _fraction(name: str):
@@ -65,6 +67,15 @@ def _rate(name: str):
                 f"to minimize profiling, not 0)")
         return val
     return parse
+
+
+def init_params(cfg, seed: int = 0, device=None):
+    """Random model weights from ``seed``, made directly on ``device``
+    (default: the first device), never staged on another chip."""
+    device = device or jax.devices()[0]
+    return jax.jit(lm.init_params, static_argnums=1,
+                   out_shardings=SingleDeviceSharding(device))(
+                       jax.random.PRNGKey(seed), cfg)
 
 
 def run_oneshot(args, cfg, params) -> None:
@@ -398,9 +409,10 @@ def main(argv=None):
         for line in build_topology(args.topology).describe():
             print(line)
 
+    place_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(
         args.arch)
-    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_params(cfg)
     if args.scheduler == "continuous" and args.replicas > 1:
         run_cluster(args, cfg, params)
     elif args.scheduler == "continuous":

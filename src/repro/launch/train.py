@@ -21,6 +21,7 @@ from ..data.pipeline import DataConfig, DataIterator
 from ..models import lm, psharding as PS, shardings as sh
 from ..optim import AdamConfig, init_state
 from . import steps as steps_mod
+from .compile_cache import place_compile_cache
 from .mesh import dp_axes, make_mesh
 
 
@@ -386,6 +387,7 @@ def main(argv=None):
         ap.error("--topology only takes effect with --adaptive (the "
                  "replanner is what plans over the topology)")
 
+    place_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(
         args.arch)
     mesh = parse_mesh(args.mesh)

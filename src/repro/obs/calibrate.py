@@ -99,30 +99,29 @@ def measure_transfer_probes(kinds: Iterable[str] = ("pinned_host",
 
     The runtime twin of ``tier_characterization.measured_host_tier_rows``
     — times ``jax.device_put`` round trips and returns bandwidth-only
-    probes (bulk copies cannot separate latency).  Kinds that fail to
-    probe (no such memory space on this backend) are skipped."""
+    probes (bulk copies cannot separate latency).  A kind the device
+    lacks raises: a probe that skipped it would leave the calibrator on
+    builder numbers without a word."""
     import time
 
     import jax
     import jax.numpy as jnp
 
-    from ..core.tiered_array import _device_sharding
+    from ..core.tiered_array import sharding_for_kind
 
-    x = jnp.zeros((n_mb * 1024 * 1024 // 4,), jnp.float32)
-    x = jax.device_put(x, _device_sharding("device"))
+    shardings = {kind: sharding_for_kind(kind) for kind in kinds}
+    x = jnp.zeros((n_mb * 1024 * 1024 // 4,), jnp.float32,
+                  device=sharding_for_kind("device"))
     jax.block_until_ready(x)
     out: List[TierProbe] = []
-    for kind in kinds:
-        try:
-            t0 = time.perf_counter()
-            for _ in range(max(1, iters)):
-                y = jax.device_put(x, _device_sharding(kind))
-                jax.block_until_ready(y)
-            dt = (time.perf_counter() - t0) / max(1, iters)
-            if dt > 0.0:
-                out.append(TierProbe(kind, bw_GBps=n_mb / 1024 / dt))
-        except Exception:  # pragma: no cover - backend-dependent
-            continue
+    for kind, sharding in shardings.items():
+        t0 = time.perf_counter()
+        for _ in range(max(1, iters)):
+            y = jax.device_put(x, sharding)
+            jax.block_until_ready(y)
+        dt = (time.perf_counter() - t0) / max(1, iters)
+        if dt > 0.0:
+            out.append(TierProbe(kind, bw_GBps=n_mb / 1024 / dt))
     return out
 
 

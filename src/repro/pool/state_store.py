@@ -5,8 +5,8 @@ the replanner used to *plan* moves of fp32 optimizer state and stop
 there.  The store holds named pytrees (e.g. ``opt_state_fp32``) as
 block-granular ``TieredArray``s whose per-block *tier labels* live here
 (a tier name like HOST or CXL maps to a JAX memory kind only at
-``device_put`` time, so logically distinct tiers stay distinct on
-single-memory CI hosts), and exposes ``move_fn`` — the
+``device_put`` time, so two tiers that share a memory kind, such as
+HOST and RDRAM, stay distinct), and exposes ``move_fn`` — the
 ``MigrationExecutor`` hook that realizes an object-level byte move as
 real block re-placements, gated by the ledger's budgets and recorded
 there (the store is the physical client, so it does the recording).

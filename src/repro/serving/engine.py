@@ -707,7 +707,7 @@ class ServingEngine:
         if req.state is not RequestState.RUNNING:
             return                     # pool too tight: preempted itself
         logits, cache = self._prefill(self.params,
-                                      {"tokens": jnp.asarray(toks)})
+                                      {"tokens": np.asarray(toks)})
         self.pool.write_prefill(req.rid, cache["kv_k"][:, :, 0],
                                 cache["kv_v"][:, :, 0], L,
                                 kind=self._alloc_kind)
@@ -752,11 +752,13 @@ class ServingEngine:
                 [tbl, np.zeros((n_pad, tbl.shape[1]), np.int32)])
             toks.extend([0] * n_pad)
             lens.extend([0] * n_pad)
-        tokens = jnp.asarray(toks, jnp.int32)[:, None]
-        lengths = jnp.asarray(lens, jnp.int32)
+        # host inputs go in as numpy: jit places them with the params,
+        # where a jnp array would first land on the default device
+        tokens = np.asarray(toks, np.int32)[:, None]
+        lengths = np.asarray(lens, np.int32)
         logits, new_k, new_v, routed = self._decode_fused(
             self.params, tokens, self.pool.k_store, self.pool.v_store,
-            jnp.asarray(tbl), lengths)
+            tbl, lengths)
         if self.expert_pool is not None and routed.shape[1]:
             ids = np.asarray(routed)       # (U, n_moe, B, K)
             for u in range(ids.shape[0]):
@@ -784,24 +786,22 @@ class ServingEngine:
                 lens.append(self.pool.seq_len[req.rid])
             n_pad = B - len(batch)
             if n_pad:                  # fixed batch shape: one compile
-                z = jnp.zeros_like(kv_ks[0])
+                z = jnp.zeros_like(kv_ks[0], device=kv_ks[0].sharding)
                 kv_ks.extend([z] * n_pad)
                 kv_vs.extend([z] * n_pad)
                 toks.extend([0] * n_pad)
                 lens.extend([0] * n_pad)
             kv_k = jnp.stack(kv_ks, axis=2)  # (U, n_attn, B, S_pad, ...)
             kv_v = jnp.stack(kv_vs, axis=2)
-            tokens = jnp.asarray(toks, jnp.int32)[:, None]
-            lengths = jnp.asarray(lens, jnp.int32)
+            tokens = np.asarray(toks, np.int32)[:, None]
+            lengths = np.asarray(lens, np.int32)
             logits, new_k, new_v = self._decode(self.params, tokens,
                                                 kv_k, kv_v, lengths)
         next_toks = np.asarray(jnp.argmax(logits, axis=-1))
-        new_k = np.asarray(new_k)          # (U, n_attn, B, KV, hd)
-        new_v = np.asarray(new_v)
         now_tok = self._now()
         for i, req in enumerate(batch):
-            self.pool.append_token(req.rid, jnp.asarray(new_k[:, :, i]),
-                                   jnp.asarray(new_v[:, :, i]))
+            # new_k/new_v (U, n_attn, B, KV, hd) stay on the device
+            self.pool.append_token(req.rid, new_k[:, :, i], new_v[:, :, i])
             self.pool.touch_seq(req.rid, self._step)
             req.out_tokens.append(int(next_toks[i]))
             self.metrics.on_token(req.rid, now_tok)

@@ -39,9 +39,9 @@ Data mode has two layouts:
     per-layer stores ``(U, n_attn, num_blocks, bt, KV, hd)`` indexed by
     physical block id.  This is the layout the fused tiered-gather
     kernel computes over *directly* — ``gather_tables`` hands it the
-    int32 block-index table instead of a staging copy — so tier
-    residency becomes the ledger's logical bookkeeping (the discipline
-    single-memory CPU hosts already use for every kind).
+    int32 block-index table instead of a staging copy.  The stores stay
+    in device memory, so a block's tier in this layout is the ledger's
+    bookkeeping only: no byte moves.
 """
 from __future__ import annotations
 
@@ -145,8 +145,9 @@ class PagedKVPool:
             import jax.numpy as jnp
             shape = (spec.n_units, spec.n_attn, num_blocks,
                      block_tokens, spec.n_kv, spec.head_dim)
-            self.k_store = jnp.zeros(shape, dtype=spec.dtype)
-            self.v_store = jnp.zeros(shape, dtype=spec.dtype)
+            fast = self._sharding(FAST_KIND)
+            self.k_store = jnp.zeros(shape, dtype=spec.dtype, device=fast)
+            self.v_store = jnp.zeros(shape, dtype=spec.dtype, device=fast)
         self.slow_kind = slow_kind
         self.default_kind = default_kind or slow_kind
         self.blocks: List[KVBlock] = [
@@ -366,17 +367,23 @@ class PagedKVPool:
             self.v_store = self.v_store.at[:, :, bid, off].set(
                 v_tok.astype(self.v_store.dtype))
         elif self.spec is not None:
+            import jax
             import jax.numpy as jnp
             b = self.blocks[tbl[blk_idx]]
+            fast = self._sharding(FAST_KIND)
             if b.k is None:            # fresh tail block
-                b.k = jnp.zeros(self.spec.kv_shape, dtype=self.spec.dtype)
-                b.v = jnp.zeros(self.spec.kv_shape, dtype=self.spec.dtype)
-            b.k = b.k.at[:, :, off].set(k_tok.astype(b.k.dtype))
-            b.v = b.v.at[:, :, off].set(v_tok.astype(b.v.dtype))
+                k = v = jnp.zeros(self.spec.kv_shape, dtype=self.spec.dtype,
+                                  device=fast)
+            else:
+                # host memory kinds hold data, not compute: the update
+                # runs on the device and the block goes back to its kind
+                k = jax.device_put(b.k, fast)
+                v = jax.device_put(b.v, fast)
+            k = k.at[:, :, off].set(k_tok.astype(k.dtype))
+            v = v.at[:, :, off].set(v_tok.astype(v.dtype))
             sh = self._sharding(b.kind)
-            import jax
-            b.k = jax.device_put(b.k, sh)
-            b.v = jax.device_put(b.v, sh)
+            b.k = jax.device_put(k, sh)
+            b.v = jax.device_put(v, sh)
         self.seq_len[seq_id] = n + 1
         self._emit(seq_id,
                    write_bytes=max(self.block_nbytes()
@@ -409,9 +416,10 @@ class PagedKVPool:
             shape = list(self.spec.kv_shape)
             shape[2] = pad_blocks * self.block_tokens
             if not tbl:
-                z = jnp.zeros(tuple(shape), dtype=self.spec.dtype)
+                z = jnp.zeros(tuple(shape), dtype=self.spec.dtype,
+                              device=dev)
                 return z, z
-            idx = jnp.asarray(tbl, jnp.int32)
+            idx = np.asarray(tbl, np.int32)
 
             def take(store):
                 g = jnp.take(store, idx, axis=2)   # (U,n_attn,nb,bt,..)
@@ -430,7 +438,7 @@ class PagedKVPool:
             if b.k is None:            # allocated tail block, not written
                 if zero is None:
                     zero = jnp.zeros(self.spec.kv_shape,
-                                     dtype=self.spec.dtype)
+                                     dtype=self.spec.dtype, device=dev)
                 ks.append(zero)
                 vs.append(zero)
             else:
@@ -441,13 +449,14 @@ class PagedKVPool:
             raise ValueError(f"seq {seq_id} has {len(tbl)} blocks "
                              f"> pad_blocks={pad_blocks}")
         if n_pad:
-            z = jnp.zeros(self.spec.kv_shape, dtype=self.spec.dtype)
+            z = jnp.zeros(self.spec.kv_shape, dtype=self.spec.dtype,
+                          device=dev)
             ks.extend([z] * n_pad)
             vs.extend([z] * n_pad)
         if not ks:
             shape = list(self.spec.kv_shape)
             shape[2] = pad_blocks * self.block_tokens
-            z = jnp.zeros(tuple(shape), dtype=self.spec.dtype)
+            z = jnp.zeros(tuple(shape), dtype=self.spec.dtype, device=dev)
             return z, z
         return jnp.concatenate(ks, axis=2), jnp.concatenate(vs, axis=2)
 
@@ -500,9 +509,8 @@ class PagedKVPool:
                                 b.kind, kind, bn)
         b.kind = kind
         self.counters.migrated_bytes += bn
-        # pooled layout keeps payloads in place: residency is logical
-        # (ledger-tracked), which is how every kind behaves on a
-        # single-memory CPU host anyway
+        # pooled layout keeps payloads in place in device memory: its
+        # residency is ledger bookkeeping only
         if self.spec is not None and not self.pooled and b.k is not None:
             import jax
             sh = self._sharding(kind)
@@ -589,10 +597,8 @@ class TieredKVCache:
         """Mirror one buffer's realized per-kind bytes into the ledger
         (the TieredArray's block rounding is the truth, not the asked
         shares)."""
-        from ..core.tiered_array import LOGICAL_KINDS
         ta = self._tiered[key]
-        placement = {k: ta.bytes_on(k)
-                     for k in set(LOGICAL_KINDS) | set(ta.kinds)
+        placement = {k: ta.bytes_on(k) for k in set(ta.kinds)
                      if ta.bytes_on(k) > 0}
         if self.ledger.has(self.tenant, key):
             self.ledger.retire(self.tenant, key)

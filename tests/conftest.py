@@ -1,5 +1,6 @@
-"""Tests run on the single real CPU device (no forced device count —
-the 512-device override belongs ONLY to the dry-run)."""
+"""Tests run on the CPU with 4 devices, so a plane of one-device
+replicas gets a device each (the 512-device XLA_FLAGS override belongs
+ONLY to the dry-run)."""
 import os
 
 # keep any externally-set XLA_FLAGS from leaking a device-count override
@@ -9,8 +10,28 @@ if "host_platform_device_count" in flags:
         f for f in flags.split() if "host_platform_device_count" not in f)
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platform_name", "cpu")
+jax.config.update("jax_num_cpu_devices", 4)
+
+
+def pytest_configure(config):
+    # the TPU library admits one process per machine, so the tests that
+    # describe a TPU topology share one xdist worker through their
+    # ``xdist_group``; plain ``-n N`` ("load") would ignore the group,
+    # and "loadgroup" is "load" that honours it
+    if getattr(config.option, "dist", "no") == "load":
+        config.option.dist = "loadgroup"
+    # a worker parses the unpromoted arguments, so the controller tells it
+    if getattr(config, "workerinput", {}).get("loadgroup"):
+        config.option.loadgroup = True
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_configure_node(node):
+    node.workerinput["loadgroup"] = \
+        node.config.getvalue("dist") == "loadgroup"
 
 
 def dual_cxl_machine():

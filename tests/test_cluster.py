@@ -297,7 +297,7 @@ def test_link_scales_survive_graph_rebuilt():
 
 
 # ===================================================================== #
-# ClusterPlane end-to-end (single test device: replicas share it)       #
+# ClusterPlane end-to-end (each replica on its own CPU test device)    #
 # ===================================================================== #
 @pytest.fixture(scope="module")
 def tiny():
@@ -315,11 +315,30 @@ def _plane(cfg, params, **kw):
 def test_replica_meshes_cover_all_devices():
     meshes = replica_meshes(2)
     assert len(meshes) == 2
-    # on one test device both replicas share it; with more devices the
-    # meshes must be disjoint
     devs = [tuple(d.id for d in m.devices.flat) for m in meshes]
-    if len(jax.devices()) >= 2:
-        assert not set(devs[0]) & set(devs[1])
+    assert not set(devs[0]) & set(devs[1])
+    assert sum(map(len, devs)) == len(jax.devices())
+
+
+def test_replica_meshes_refuse_shared_devices():
+    devs = jax.devices()
+    with pytest.raises(ValueError, match="need 3 devices"):
+        replica_meshes(3, devices=devs[:2])
+    with pytest.raises(ValueError, match="listed twice"):
+        replica_meshes(2, devices=[devs[0], devs[0]])
+
+
+def test_replica_refuses_arrays_off_its_mesh(tiny):
+    cfg, params = tiny
+    plane = _plane(cfg, params)
+    rep = plane.replicas["host1"]
+    assert set(rep.mesh.devices.flat).isdisjoint({jax.devices()[0]})
+    rep.check_placement()
+    # a block array made with no device lands on chip 0
+    blk = rep.engine.pool.blocks[0]
+    blk.k = blk.v = jax.numpy.zeros(rep.engine.pool.spec.kv_shape)
+    with pytest.raises(RuntimeError, match="outside its mesh"):
+        rep.check_placement()
 
 
 def test_plane_routes_runs_and_conserves_namespaces(tiny):

@@ -121,3 +121,31 @@ def test_decode_attention_property(s_mult, kv, rep):
     want = ref.decode_attention(q, kc, vc, jnp.int32(S))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
+
+
+# --------------------------- backend selection ------------------------ #
+@pytest.mark.parametrize("entry", [
+    "decode_attention.decode_attention", "flash_attention.flash_attention",
+    "flash_attention.flash_attention_bh", "fused_adam.fused_adam",
+    "fused_adam.fused_adam_2d", "tiered_gather.paged_decode_attention",
+    "tiered_gather.fused_expert_ffn"])
+def test_kernel_entries_require_interpret(entry):
+    """A direct caller must say how the kernel runs: a default of
+    interpret=True would run the interpreter on the chip in silence."""
+    import importlib
+    import inspect
+    mod, name = entry.split(".")
+    fn = getattr(importlib.import_module(f"repro.kernels.{mod}"), name)
+    param = inspect.signature(fn).parameters["interpret"]
+    assert param.default is inspect.Parameter.empty
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_interpret_follows_backend(monkeypatch, backend, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="not on 'gpu'"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is want

@@ -141,3 +141,40 @@ def test_cast_absorbs_read_at_source_width():
     cost = JC.step_cost(deq, c8)
     # charged at int8 width (+ small reduce output), not fp32
     assert cost["bytes"] < 1024 * 128 * 2
+
+
+def test_compile_cache_defaults_to_repo_dir(monkeypatch):
+    from repro.launch import compile_cache as cc
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = cc.place_compile_cache()
+        assert path == str(cc.REPO_CACHE) == jax.config.jax_compilation_cache_dir
+        assert cc.REPO_CACHE.parent.joinpath("pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_dir_is_the_only_one(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, entries land there, and JAX's
+    one cache directory is still that one after a compile."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.launch import compile_cache as cc
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(cc.REPO_CACHE.parent / "src"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import place_compile_cache\n"
+            "print(place_compile_cache())\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [str(tmp_path)] * 2
+    assert os.listdir(tmp_path), "no cache entry was written"

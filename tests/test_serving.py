@@ -147,6 +147,31 @@ def test_gather_seq_roundtrips_written_payload(pooled):
     assert float(jnp.abs(k[:, :, 8:]).sum()) == 0.0   # pad block zero
 
 
+def test_append_token_keeps_block_in_its_memory_kind():
+    """The tail-block update runs on the device, and the block goes
+    back to its own kind; the gathered copy lands on the device."""
+    pool, spec = _data_pool(pooled=False)
+    rs = np.random.RandomState(1)
+    kv_k = jnp.asarray(rs.randn(1, 2, 3, 2, 8), jnp.float32)
+    pool.write_prefill(5, kv_k, kv_k, n_tokens=3, kind="pinned_host")
+    tok = jnp.asarray(rs.randn(1, 2, 2, 8), jnp.float32)
+    pool.append_token(5, tok, -tok)             # fills the block's tail
+    pool.alloc(5, 1, kind="pinned_host")
+    pool.append_token(5, tok, tok)              # a fresh tail block
+    for b in pool.seq_blocks(5):
+        assert b.k.sharding.memory_kind == "pinned_host"
+        assert b.v.sharding.memory_kind == "pinned_host"
+    k, v = pool.gather_seq(5, 3)
+    assert k.sharding.memory_kind == "device"
+    np.testing.assert_array_equal(np.asarray(k[:, :, :3]),
+                                  np.asarray(kv_k))
+    np.testing.assert_array_equal(np.asarray(k[:, :, 3]), np.asarray(tok))
+    np.testing.assert_array_equal(np.asarray(v[:, :, 3]),
+                                  np.asarray(-tok))
+    np.testing.assert_array_equal(np.asarray(k[:, :, 4]), np.asarray(tok))
+    assert float(jnp.abs(k[:, :, 5:]).sum()) == 0.0
+
+
 def test_gather_tables_requires_pooled_layout():
     pool, _ = _data_pool(pooled=False)
     pool.alloc(1, 2)

@@ -1,10 +1,13 @@
 """TieredArray: block placement over memory kinds, gather/update."""
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hyp import given, settings, st
 
 from repro.core import (available_memory_kinds, gather_pytree, place_pytree,
                         TieredArray)
+from repro.core.tiered_array import sharding_for_kind
 
 
 def test_roundtrip_contiguous():
@@ -68,3 +71,31 @@ def test_roundtrip_property(rows, cols, frac, block):
     np.testing.assert_array_equal(np.asarray(ta.gather()), np.asarray(x))
     total_rows = sum(b.shape[0] for b in ta.blocks)
     assert total_rows == rows
+
+
+def _mesh_pool_kind(kind):
+    from jax.sharding import Mesh
+
+    from repro.cluster.replica import _mesh_pool_sharding
+    return _mesh_pool_sharding(Mesh(np.array(jax.devices()[:1]), ("m",)))(
+        kind)
+
+
+def _probe_kind(kind):
+    from repro.obs import measure_transfer_probes
+    return measure_transfer_probes(kinds=(kind,), n_mb=1, iters=1)
+
+
+# every placement entry refuses a memory kind the device lacks, where it
+# once fell back to default memory and hid a wrong tier
+@pytest.mark.parametrize("place", [
+    sharding_for_kind,
+    lambda kind: TieredArray.place(jnp.ones((4, 2)), [(kind, 1.0)]),
+    _mesh_pool_kind,
+    _probe_kind,
+], ids=["sharding_for_kind", "TieredArray.place", "mesh_pool_sharding",
+        "measure_transfer_probes"])
+def test_missing_memory_kind_raises(place):
+    assert "hbm_far" not in available_memory_kinds()
+    with pytest.raises(ValueError, match="no memory kind 'hbm_far'"):
+        place("hbm_far")

@@ -107,18 +107,29 @@ def _expert_inputs(seed, E, D, F, B, K, dtype=jnp.float32):
     return x, wg, wu, wd, ids, wts.astype(dtype)
 
 
-@pytest.mark.parametrize("E,D,F,B,K", [
-    (4, 16, 32, 1, 1),
-    (8, 64, 128, 6, 2),
-    (16, 32, 64, 5, 4),
+@pytest.mark.parametrize("E,D,F,B,K,dtype,tol", [
+    (4, 16, 32, 1, 1, jnp.float32, 2e-3),
+    (8, 64, 128, 6, 2, jnp.float32, 2e-3),
+    (16, 32, 64, 5, 4, jnp.float32, 2e-3),
+    # F spans several BLOCK_F tiles, as at published MoE widths
+    (4, 64, 512, 3, 2, jnp.float32, 2e-3),
+    (8, 32, 768, 2, 3, jnp.float32, 2e-3),
+    (8, 64, 768, 3, 2, jnp.bfloat16, 3e-2),
 ])
-def test_fused_expert_ffn_sweep(E, D, F, B, K):
-    x, wg, wu, wd, ids, wts = _expert_inputs(0, E, D, F, B, K)
+def test_fused_expert_ffn_sweep(E, D, F, B, K, dtype, tol):
+    x, wg, wu, wd, ids, wts = _expert_inputs(0, E, D, F, B, K, dtype)
     got = ops.fused_expert_ffn(x, wg, wu, wd, ids, wts)
     want = ref.expert_ffn(x, wg, wu, wd, ids, wts)
-    assert got.shape == (B, D)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-3, atol=2e-3)
+    assert got.shape == (B, D) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_fused_expert_ffn_rejects_ragged_f_tiles():
+    x, wg, wu, wd, ids, wts = _expert_inputs(0, 2, 16, 384, 1, 1)
+    with pytest.raises(ValueError, match="BLOCK_F"):
+        ops.fused_expert_ffn(x, wg, wu, wd, ids, wts)
 
 
 def test_fused_expert_ffn_duplicate_experts():
