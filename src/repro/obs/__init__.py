@@ -7,7 +7,8 @@ the same treatment:
 - trace:    ring-bounded structured spans/events across the decision
             path (phase detect -> arbiter grant -> replan verdict ->
             move round -> executed deltas), exportable as JSONL and
-            Chrome trace_event JSON
+            Chrome trace_event JSON; ``annotate`` puts a span on the
+            profiler's clock, beside the device's operations
 - registry: central counters/gauges/histograms with DDSketch-style
             streaming percentile sketches + Prometheus text exporter
 - slo:      live rolling-window SLO monitors (TTFT / decode latency
@@ -33,10 +34,12 @@ from .qos import (BlameLedger, Excursion, QOS_VIOLATION_MODEL,
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        PercentileSketch)
 from .slo import LagRatioMonitor, SLOMonitor, SLOTarget
-from .trace import qos_chains, replan_chains, TraceEvent, TraceRecorder
+from .trace import (annotate, qos_chains, replan_chains, TraceEvent,
+                    TraceRecorder)
 
 __all__ = [
-    "TraceEvent", "TraceRecorder", "qos_chains", "replan_chains",
+    "TraceEvent", "TraceRecorder", "annotate", "qos_chains",
+    "replan_chains",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "PercentileSketch",
     "LagRatioMonitor", "SLOMonitor", "SLOTarget",
     "DriftDetector", "PredictionLedger", "PredictionRecord",

@@ -15,6 +15,14 @@ Event phases follow the trace_event vocabulary we need:
                         rounds; the MoveScheduler's fluid schedule gives
                         exact start/finish times)
 - ``"C"``  counter   -- a sampled numeric series
+
+The profiler's clock is the other timeline: :func:`annotate` opens a
+``jax.profiler.TraceAnnotation``, which lands in a device trace beside
+the device's own operations when ``jax.profiler`` is recording, and
+costs about a microsecond when it is not.  ``TraceRecorder.span``
+opens one too, so control-plane spans show on both timelines.  The
+serving loop's hot-path spans (``serve.*``, ``kv.*``, ``tier.*``) go
+through :func:`annotate` alone and never into a recorder's ring.
 """
 from __future__ import annotations
 
@@ -24,7 +32,17 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, Iterable, Iterator, List, Optional
 
-__all__ = ["TraceEvent", "TraceRecorder", "qos_chains", "replan_chains"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["TraceEvent", "TraceRecorder", "annotate", "qos_chains",
+           "replan_chains"]
+
+
+def annotate(name: str, **args: int) -> TraceAnnotation:
+    """A span on the profiler's clock.  ``name`` is a fixed string;
+    each integer in ``args`` becomes a stat of the trace event.  The
+    args are taken when the span opens."""
+    return TraceAnnotation(name, **args)
 
 
 def _json_safe(value: Any) -> Any:
@@ -148,22 +166,24 @@ class TraceRecorder:
     @contextmanager
     def span(self, name: str, cat: str = "obs", tid: str = "main",
              **args: Any) -> Iterator[Dict[str, Any]]:
-        """Time a block of code as a complete event.
+        """Time a block of code as a complete event, and as a span of
+        the same name on the profiler's clock.
 
         Yields the args dict so the body can attach results before the
         span closes.
         """
         safe = {k: _json_safe(v) for k, v in args.items()}
         start = float(self.clock())
-        try:
-            yield safe
-        finally:
-            end = float(self.clock())
-            self._push(TraceEvent(
-                name=name, cat=cat, ph="X", ts_s=start,
-                dur_s=max(0.0, end - start), tid=tid,
-                args={k: _json_safe(v) for k, v in safe.items()},
-            ))
+        with annotate(name):
+            try:
+                yield safe
+            finally:
+                end = float(self.clock())
+                self._push(TraceEvent(
+                    name=name, cat=cat, ph="X", ts_s=start,
+                    dur_s=max(0.0, end - start), tid=tid,
+                    args={k: _json_safe(v) for k, v in safe.items()},
+                ))
 
     # ----------------------------------------------------------- query
     def filter(self, name: Optional[str] = None, cat: Optional[str] = None,

@@ -16,7 +16,7 @@ from .engine import (check_paged_support, kind_tiers, ServingConfig,
 from .expert_pool import ExpertCounters, ExpertPool
 from .kv_pool import (FAST_KIND, KVBlock, KVBlockSpec, PagedKVPool,
                       PoolExhausted, spec_from_config, TieredKVCache)
-from .metrics import percentile, PoolSample, RequestMetrics, ServingMetrics
+from .metrics import percentile, RequestMetrics, ServingMetrics
 from .scheduler import (AdmissionPlan, ContinuousBatchingScheduler,
                         plan_admission, Request, RequestState,
                         SchedulerConfig)
@@ -29,7 +29,7 @@ __all__ = [
     "KVBlockTierer", "POLICIES", "TieringStats", "make_tiering_policy",
     "AdmissionPlan", "ContinuousBatchingScheduler", "Request",
     "RequestState", "SchedulerConfig", "plan_admission",
-    "PoolSample", "RequestMetrics", "ServingMetrics", "percentile",
+    "RequestMetrics", "ServingMetrics", "percentile",
     "ServingConfig", "ServingEngine", "ServingReport",
     "check_paged_support", "kind_tiers",
     "ExpertCounters", "ExpertPool",

@@ -11,6 +11,15 @@ positions feed RoPE/learned embeddings, so sequences of different
 lengths decode in one batch (the thing the one-shot FlexGenEngine
 cannot do).
 
+The serving loop puts spans on the profiler's clock
+(``obs.trace.annotate``): ``serve.iteration`` around each iteration and
+``serve.*`` spans around its phases, carrying the cumulative token and
+KV transfer counts (``_counters``) as args; the pool adds ``kv.*``
+spans.  The jitted programs are named ``serve_prefill``,
+``serve_decode`` and ``serve_decode_fused``, and the decode program's
+ops fall under the scopes ``attention``, ``kv_write``, ``mlp`` and
+``head``.
+
 Supported configs: attention-only patterns (optionally MoE) with
 rope/learned/none positions and bf16 KV — the serving family of the
 paper's Sec. IV-B study.  Hybrid SSM/RWKV decode stays on the one-shot
@@ -35,6 +44,7 @@ from ..core.tiers import GiB, MemoryTier, tpu_v5e_tiers
 from ..kernels import ops
 from ..launch import steps as steps_mod
 from ..models import modules as M
+from ..obs.trace import annotate
 from . import config as config_mod
 from ..telemetry import (AccessSampler, AccessTrace, AdaptiveReplanner,
                          PhaseDetector, ReplanConfig, SamplerConfig)
@@ -82,40 +92,44 @@ def _paged_unit_fwd(cfg: ModelConfig, up, x, kv_k, kv_v, lengths,
     i_attn = 0
     for li, spec in enumerate(cfg.pattern):
         lp = up["layers"][li]
-        h = M.apply_norm(cfg.norm, lp["norm1"], x)
         ap = lp["attn"]
-        q = h @ ap["wq"]
-        k = h @ ap["wk"]
-        v = h @ ap["wv"]
-        if "bq" in ap:
-            q = q + ap["bq"]
-        if "bk" in ap:
-            k = k + ap["bk"]
-            v = v + ap["bv"]
-        q = q.reshape(B, 1, H, hd)
-        k = k.reshape(B, 1, KV, hd)
-        v = v.reshape(B, 1, KV, hd)
-        if cfg.pos_emb == "rope":
-            pos = lengths[:, None]                     # per-seq positions
-            q = M.apply_rope(q, pos, cfg.rope_theta, cfg.rotary_pct)
-            k = M.apply_rope(k, pos, cfg.rope_theta, cfg.rotary_pct)
+        with jax.named_scope("attention"):
+            h = M.apply_norm(cfg.norm, lp["norm1"], x)
+            q = h @ ap["wq"]
+            k = h @ ap["wk"]
+            v = h @ ap["wv"]
+            if "bq" in ap:
+                q = q + ap["bq"]
+            if "bk" in ap:
+                k = k + ap["bk"]
+                v = v + ap["bv"]
+            q = q.reshape(B, 1, H, hd)
+            k = k.reshape(B, 1, KV, hd)
+            v = v.reshape(B, 1, KV, hd)
+            if cfg.pos_emb == "rope":
+                pos = lengths[:, None]                 # per-seq positions
+                q = M.apply_rope(q, pos, cfg.rope_theta, cfg.rotary_pct)
+                k = M.apply_rope(k, pos, cfg.rope_theta, cfg.rotary_pct)
         ck, cv = kv_k[i_attn], kv_v[i_attn]            # (B, S_pad, KV, hd)
         k_tok = k[:, 0].astype(ck.dtype)
         v_tok = v[:, 0].astype(cv.dtype)
-        ck = ck.at[barange, lengths].set(k_tok)
-        cv = cv.at[barange, lengths].set(v_tok)
-        att = ops.decode_attention(q[:, 0], ck, cv, lengths + 1,
-                                   block_k=block_k)    # (B, H, hd)
-        x = x + (att.reshape(B, 1, H * hd) @ ap["wo"])
+        with jax.named_scope("kv_write"):
+            ck = ck.at[barange, lengths].set(k_tok)
+            cv = cv.at[barange, lengths].set(v_tok)
+        with jax.named_scope("attention"):
+            att = ops.decode_attention(q[:, 0], ck, cv, lengths + 1,
+                                       block_k=block_k)  # (B, H, hd)
+            x = x + (att.reshape(B, 1, H * hd) @ ap["wo"])
 
-        h = M.apply_norm(cfg.norm, lp["norm2"], x)
-        if spec.moe:
-            out, _ = M.moe_fwd(lp["moe"], h, top_k=cfg.top_k,
-                               capacity_factor=cfg.capacity_factor,
-                               n_groups=cfg.moe_groups, act=cfg.act)
-        else:
-            out = M.mlp_fwd(lp["mlp"], h, cfg.act)
-        x = x + out
+        with jax.named_scope("mlp"):
+            h = M.apply_norm(cfg.norm, lp["norm2"], x)
+            if spec.moe:
+                out, _ = M.moe_fwd(lp["moe"], h, top_k=cfg.top_k,
+                                   capacity_factor=cfg.capacity_factor,
+                                   n_groups=cfg.moe_groups, act=cfg.act)
+            else:
+                out = M.mlp_fwd(lp["mlp"], h, cfg.act)
+            x = x + out
         new_ks.append(k_tok)
         new_vs.append(v_tok)
         i_attn += 1
@@ -139,9 +153,10 @@ def _paged_decode(cfg: ModelConfig, block_k: int, params, tokens,
         return h, (nk, nv)
 
     x, (new_k, new_v) = lax.scan(body, x, (params["units"], kv_k, kv_v))
-    x = M.apply_norm(cfg.norm, params["final_norm"], x)
-    W = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = (x[:, 0] @ W.T).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = M.apply_norm(cfg.norm, params["final_norm"], x)
+        W = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+        logits = (x[:, 0] @ W.T).astype(jnp.float32)
     return logits, new_k, new_v
 
 
@@ -240,6 +255,13 @@ def _fused_paged_decode(cfg: ModelConfig, block_tokens: int, params,
     W = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = (x[:, 0] @ W.T).astype(jnp.float32)
     return logits, new_k, new_v, routed
+
+
+def _program(fn: Callable, name: str):
+    """``fn`` jitted under ``name``, the name its program carries in a
+    device trace (a ``functools.partial`` has none of its own)."""
+    fn.__name__ = name
+    return jax.jit(fn)
 
 
 # ---------------------------------------------------------------------- #
@@ -636,12 +658,16 @@ class ServingEngine:
                 fast_expert_budget=budget, policy=sv.expert_policy,
                 tenant=f"{sv.tenant}.experts", slow_kind=sv.slow_kind,
                 movesched=self.movesched, tracer=self.tracer)
-        self._prefill = jax.jit(steps_mod.make_prefill_step(cfg))
-        self._decode = jax.jit(functools.partial(_paged_decode, cfg, bt))
+        self._prefill = _program(steps_mod.make_prefill_step(cfg),
+                                 "serve_prefill")
+        self._decode = _program(functools.partial(_paged_decode, cfg, bt),
+                                "serve_decode")
         self._decode_fused = (
-            jax.jit(functools.partial(_fused_paged_decode, cfg, bt))
+            _program(functools.partial(_fused_paged_decode, cfg, bt),
+                     "serve_decode_fused")
             if sv.fused_gather else None)
         self._next_rid = 0
+        self._prefill_tokens = 0      # prompt tokens prefilled
 
     # ------------------------------------------------------------------ #
     def submit(self, prompt: np.ndarray, max_new_tokens: int,
@@ -697,27 +723,40 @@ class ServingEngine:
                 return FAST_KIND
         return None           # pool default (slow kind)
 
+    def _counters(self) -> Dict[str, int]:
+        """The cumulative counts a hot-path span carries as its args:
+        tokens handed over, prompt tokens prefilled, and the pool's KV
+        transfers between host memory and the device."""
+        c = self.pool.counters
+        return dict(tokens_out=self.metrics.decode_tokens,
+                    prefill_tokens=self._prefill_tokens,
+                    kv_h2d_bytes=c.h2d_bytes, kv_d2h_bytes=c.d2h_bytes,
+                    kv_h2d_puts=c.h2d_puts, kv_d2h_puts=c.d2h_puts)
+
     def _do_prefill(self, req: Request, now: float) -> None:
         toks = req.prefill_tokens()[None]          # (1, L)
         L = toks.shape[1]
-        need = self.pool.blocks_for_tokens(L + 1)
-        if not self.pool.can_alloc(need):
-            for v in self.sched.preempt_for_blocks(need, protect=req):
-                self.metrics.on_preempt(v.rid, now)
-        if req.state is not RequestState.RUNNING:
-            return                     # pool too tight: preempted itself
-        logits, cache = self._prefill(self.params,
-                                      {"tokens": np.asarray(toks)})
-        self.pool.write_prefill(req.rid, cache["kv_k"][:, :, 0],
-                                cache["kv_v"][:, :, 0], L,
-                                kind=self._alloc_kind)
-        self.metrics.on_admit(req.rid, now)
-        tok = int(np.asarray(jnp.argmax(logits[0])))
-        req.out_tokens.append(tok)
-        self.metrics.on_token(req.rid, self._now())
-        if req.done:
-            self.sched.finish(req)
-            self.metrics.on_finish(req.rid, self._now(), req.preemptions)
+        with annotate("serve.prefill", rid=req.rid, tokens=L):
+            need = self.pool.blocks_for_tokens(L + 1)
+            if not self.pool.can_alloc(need):
+                for v in self.sched.preempt_for_blocks(need, protect=req):
+                    self.metrics.on_preempt(v.rid, now)
+            if req.state is not RequestState.RUNNING:
+                return                 # pool too tight: preempted itself
+            logits, cache = self._prefill(self.params,
+                                          {"tokens": np.asarray(toks)})
+            self._prefill_tokens += L
+            self.pool.write_prefill(req.rid, cache["kv_k"][:, :, 0],
+                                    cache["kv_v"][:, :, 0], L,
+                                    kind=self._alloc_kind)
+            self.metrics.on_admit(req.rid, now)
+            tok = int(np.asarray(jnp.argmax(logits[0])))
+            req.out_tokens.append(tok)
+            self.metrics.on_token(req.rid, self._now())
+            if req.done:
+                self.sched.finish(req)
+                self.metrics.on_finish(req.rid, self._now(),
+                                       req.preemptions)
 
     def _ensure_tail_blocks(self) -> None:
         """Every running request needs a block for its next KV write."""
@@ -737,77 +776,83 @@ class ServingEngine:
                 continue               # preempted itself
             self.pool.alloc(req.rid, 1, kind=self._alloc_kind)
 
-    def _fused_decode_batch(self, batch):
-        """Fused tiered-gather decode: no per-sequence staging copy —
-        the jitted step reads the pooled stores through each sequence's
-        block-index table.  Routed expert ids feed per-expert heat."""
-        B = self.max_batch
-        tbl, _ = self.pool.gather_tables([r.rid for r in batch],
-                                         self.max_seq_blocks)
-        toks = [req.out_tokens[-1] for req in batch]
-        lens = [self.pool.seq_len[req.rid] for req in batch]
-        n_pad = B - len(batch)
-        if n_pad:                      # fixed batch shape: one compile
-            tbl = np.concatenate(
-                [tbl, np.zeros((n_pad, tbl.shape[1]), np.int32)])
-            toks.extend([0] * n_pad)
-            lens.extend([0] * n_pad)
-        # host inputs go in as numpy: jit places them with the params,
-        # where a jnp array would first land on the default device
-        tokens = np.asarray(toks, np.int32)[:, None]
-        lengths = np.asarray(lens, np.int32)
-        logits, new_k, new_v, routed = self._decode_fused(
-            self.params, tokens, self.pool.k_store, self.pool.v_store,
-            tbl, lengths)
-        if self.expert_pool is not None and routed.shape[1]:
-            ids = np.asarray(routed)       # (U, n_moe, B, K)
-            for u in range(ids.shape[0]):
-                for m in range(ids.shape[1]):
-                    gl = u * self._moe_per_unit + m
-                    for i in range(len(batch)):
-                        self.expert_pool.record_routing(
-                            gl, ids[u, m, i], self._step)
-        return logits, new_k, new_v
+    def _gather_kv(self, batch) -> tuple:
+        """The decode step's KV inputs for ``batch``, padded to the
+        fixed batch shape (one compile).  Fused tiered-gather decode:
+        the pooled stores and the batch's block-index tables, which the
+        jitted step reads through — no staging copy.  Staged decode:
+        each sequence's blocks gathered onto the device and stacked,
+        (U, n_attn, B, S_pad, KV, hd) for K and for V."""
+        n_pad = self.max_batch - len(batch)
+        if self._decode_fused is not None:
+            tbl, _ = self.pool.gather_tables([r.rid for r in batch],
+                                             self.max_seq_blocks)
+            if n_pad:
+                tbl = np.concatenate(
+                    [tbl, np.zeros((n_pad, tbl.shape[1]), np.int32)])
+            return self.pool.k_store, self.pool.v_store, tbl
+        kv_ks, kv_vs = [], []
+        for req in batch:
+            k, v = self.pool.gather_seq(req.rid, self.max_seq_blocks)
+            kv_ks.append(k)
+            kv_vs.append(v)
+        if n_pad:
+            z = jnp.zeros_like(kv_ks[0], device=kv_ks[0].sharding)
+            kv_ks.extend([z] * n_pad)
+            kv_vs.extend([z] * n_pad)
+        return jnp.stack(kv_ks, axis=2), jnp.stack(kv_vs, axis=2)
+
+    def _record_routing(self, n_seqs: int, routed) -> None:
+        """Routed expert ids (U, n_moe, B, K) feed per-expert heat."""
+        ids = np.asarray(routed)
+        for u in range(ids.shape[0]):
+            for m in range(ids.shape[1]):
+                gl = u * self._moe_per_unit + m
+                for i in range(n_seqs):
+                    self.expert_pool.record_routing(gl, ids[u, m, i],
+                                                    self._step)
 
     def _decode_iteration(self, now: float) -> None:
         batch = list(self.sched.running)
         if not batch:
             return
-        B = self.max_batch
-        if self._decode_fused is not None:
-            logits, new_k, new_v = self._fused_decode_batch(batch)
-        else:
-            kv_ks, kv_vs, toks, lens = [], [], [], []
-            for req in batch:
-                k, v = self.pool.gather_seq(req.rid, self.max_seq_blocks)
-                kv_ks.append(k)
-                kv_vs.append(v)
-                toks.append(req.out_tokens[-1])
-                lens.append(self.pool.seq_len[req.rid])
-            n_pad = B - len(batch)
-            if n_pad:                  # fixed batch shape: one compile
-                z = jnp.zeros_like(kv_ks[0], device=kv_ks[0].sharding)
-                kv_ks.extend([z] * n_pad)
-                kv_vs.extend([z] * n_pad)
-                toks.extend([0] * n_pad)
-                lens.extend([0] * n_pad)
-            kv_k = jnp.stack(kv_ks, axis=2)  # (U, n_attn, B, S_pad, ...)
-            kv_v = jnp.stack(kv_vs, axis=2)
-            tokens = np.asarray(toks, np.int32)[:, None]
-            lengths = np.asarray(lens, np.int32)
-            logits, new_k, new_v = self._decode(self.params, tokens,
-                                                kv_k, kv_v, lengths)
-        next_toks = np.asarray(jnp.argmax(logits, axis=-1))
+        n_pad = self.max_batch - len(batch)
+        # host inputs go in as numpy: jit places them with the params,
+        # where a jnp array would first land on the default device
+        tokens = np.asarray([r.out_tokens[-1] for r in batch] + [0] * n_pad,
+                            np.int32)[:, None]
+        lengths = np.asarray([self.pool.seq_len[r.rid] for r in batch]
+                             + [0] * n_pad, np.int32)
+        blocks = sum(len(self.pool.table.get(r.rid, ())) for r in batch)
+        with annotate("kv.gather", seqs=len(batch), blocks=blocks,
+                      **self._counters()):
+            kv = self._gather_kv(batch)
+        routed = None
+        with annotate("serve.decode", **self._counters()):
+            if self._decode_fused is not None:
+                logits, new_k, new_v, routed = self._decode_fused(
+                    self.params, tokens, *kv, lengths)
+            else:
+                logits, new_k, new_v = self._decode(self.params, tokens,
+                                                    *kv, lengths)
+        with annotate("serve.sync"):
+            next_toks = np.asarray(jnp.argmax(logits, axis=-1))
         now_tok = self._now()
-        for i, req in enumerate(batch):
-            # new_k/new_v (U, n_attn, B, KV, hd) stay on the device
-            self.pool.append_token(req.rid, new_k[:, :, i], new_v[:, :, i])
-            self.pool.touch_seq(req.rid, self._step)
-            req.out_tokens.append(int(next_toks[i]))
-            self.metrics.on_token(req.rid, now_tok)
-            if req.done:
-                self.sched.finish(req)
-                self.metrics.on_finish(req.rid, now_tok, req.preemptions)
+        with annotate("serve.deliver"):
+            if (routed is not None and self.expert_pool is not None
+                    and routed.shape[1]):
+                self._record_routing(len(batch), routed)
+            for i, req in enumerate(batch):
+                # new_k/new_v (U, n_attn, B, KV, hd) stay on the device
+                self.pool.append_token(req.rid, new_k[:, :, i],
+                                       new_v[:, :, i])
+                self.pool.touch_seq(req.rid, self._step)
+                req.out_tokens.append(int(next_toks[i]))
+                self.metrics.on_token(req.rid, now_tok)
+                if req.done:
+                    self.sched.finish(req)
+                    self.metrics.on_finish(req.rid, now_tok,
+                                           req.preemptions)
 
     # ------------------------------------------------------------------ #
     def _move_seq_blocks(self, obj: str, src: str, dst: str,
@@ -986,12 +1031,12 @@ class ServingEngine:
         ``arrival_s`` values."""
         return self.clock() - self._t0 + self._virtual_skew
 
-    def run(self, max_iterations: int = 10_000) -> ServingReport:
-        """Drive the trace to completion; returns the serving report."""
-        self._t0 = self.clock()
-        self._virtual_skew = 0.0
-        while self.sched.active and self._step < max_iterations:
-            now = self._now()
+    def _iteration(self) -> None:
+        """One pass of the loop: schedule, prefill what was admitted,
+        decode the batch, tier and replan.  An idle pass fast-forwards
+        the arrival clock and counts no step."""
+        now = self._now()
+        with annotate("serve.schedule", admitted=self.metrics.prefills):
             # an arbiter may have shrunk this tenant's fast budget in
             # the shared ledger since the last iteration: enforce it
             # before admitting new work (freed blocks re-admit victims)
@@ -1002,30 +1047,43 @@ class ServingEngine:
             for v in self.sched.preempt_predicted_violation():
                 self.metrics.on_preempt(v.rid, now)
             admitted = self.sched.admit(now_s=now)
-            if not admitted and not self.sched.running:
-                # idle: fast-forward the arrival clock (synthetic traces)
-                pending = [r.arrival_s for r in self.sched.waiting]
-                skip = max(min(pending) - now, 0.0) if pending else 0.0
-                if skip <= 0.0:
-                    raise RuntimeError(
-                        "scheduler stalled: waiting requests cannot be "
-                        "admitted into an empty pool (pool too small)")
-                self._virtual_skew += skip
-                continue
-            for req in admitted:
-                self._do_prefill(req, now)
+        if not admitted and not self.sched.running:
+            # idle: fast-forward the arrival clock (synthetic traces)
+            pending = [r.arrival_s for r in self.sched.waiting]
+            skip = max(min(pending) - now, 0.0) if pending else 0.0
+            if skip <= 0.0:
+                raise RuntimeError(
+                    "scheduler stalled: waiting requests cannot be "
+                    "admitted into an empty pool (pool too small)")
+            self._virtual_skew += skip
+            return
+        for req in admitted:
+            self._do_prefill(req, now)
+        with annotate("kv.ensure_tail"):
             self._ensure_tail_blocks()
-            self._decode_iteration(now)
-            if self.sv.migrate_every and \
-                    self._step % self.sv.migrate_every == 0:
+        self._decode_iteration(now)
+        if self.sv.migrate_every and \
+                self._step % self.sv.migrate_every == 0:
+            with annotate("tier.step",
+                          migrated=self.pool.counters.migrated_bytes):
                 self.tierer.step(
                     [r.rid for r in self.sched.running], self._step)
+        with annotate("serve.control"):
             self._replan_step()
-            self.metrics.on_iteration(
-                self._step, self.pool.used_block_count(),
-                self.pool.fast_used(), len(self.sched.running),
-                len(self.sched.waiting))
-            self._step += 1
+        self.metrics.on_iteration(self.pool.used_block_count(),
+                                  len(self.sched.running))
+        self._step += 1
+
+    def run(self, max_iterations: int = 10_000) -> ServingReport:
+        """Drive the trace to completion; returns the serving report."""
+        self._t0 = self.clock()
+        self._virtual_skew = 0.0
+        while self.sched.active and self._step < max_iterations:
+            with jax.profiler.StepTraceAnnotation(
+                    "serve.iteration", step_num=self._step,
+                    running=len(self.sched.running),
+                    waiting=len(self.sched.waiting), **self._counters()):
+                self._iteration()
         tstats = self.tierer.stats.as_dict()
         # adaptive replan moves also migrate pool blocks; surface them in
         # the tiering counters the report exposes

@@ -42,6 +42,11 @@ Data mode has two layouts:
     int32 block-index table instead of a staging copy.  The stores stay
     in device memory, so a block's tier in this layout is the ledger's
     bookkeeping only: no byte moves.
+
+Every ``device_put`` that carries a block between a host memory kind
+and the device is counted in ``PoolCounters`` (bytes and puts, each
+direction), and each method that moves payloads opens a ``kv.*`` span
+on the profiler's clock (``obs.trace.annotate``).
 """
 from __future__ import annotations
 
@@ -50,6 +55,8 @@ import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from ..obs.trace import annotate
 
 FAST_KIND = "device"
 
@@ -108,6 +115,12 @@ class PoolCounters:
     demoted: int = 0
     migrated_bytes: int = 0
     defrags: int = 0
+    # payload transfers between a host memory kind and the device
+    # (PCIe on a TPU host); a block's (k, v) is two puts
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+    h2d_puts: int = 0
+    d2h_puts: int = 0
 
 
 class PagedKVPool:
@@ -299,6 +312,19 @@ class PagedKVPool:
     # ------------------------------------------------------------------ #
     # payload I/O (data mode)                                            #
     # ------------------------------------------------------------------ #
+    def _count_transfer(self, src: str, dst: str) -> None:
+        """Count one block's (k, v) put from kind ``src`` to ``dst``
+        when it crosses between a host kind and the device."""
+        if (src == FAST_KIND) == (dst == FAST_KIND):
+            return
+        c, bn = self.counters, self.block_nbytes()
+        if dst == FAST_KIND:
+            c.h2d_bytes += bn
+            c.h2d_puts += 2
+        else:
+            c.d2h_bytes += bn
+            c.d2h_puts += 2
+
     def _sharding(self, kind: str):
         if self.sharding_fn is not None:
             return self.sharding_fn(kind)
@@ -306,7 +332,8 @@ class PagedKVPool:
         return sharding_for_kind(kind)
 
     def write_block(self, bid: int, k, v) -> None:
-        """Place (k, v) payloads on the block's current kind."""
+        """Place (k, v) payloads, which are on the device (a prefill's
+        cache), on the block's current kind."""
         if self.spec is None:
             return
         if self.pooled:
@@ -322,6 +349,7 @@ class PagedKVPool:
         sh = self._sharding(b.kind)
         b.k = jax.device_put(k, sh)
         b.v = jax.device_put(v, sh)
+        self._count_transfer(FAST_KIND, b.kind)
 
     def write_prefill(self, seq_id: int, kv_k, kv_v, n_tokens: int,
                       kind: Optional[str] = None) -> None:
@@ -332,18 +360,19 @@ class PagedKVPool:
         """
         bt = self.block_tokens
         n_blocks = self.blocks_for_tokens(n_tokens)
-        pad = n_blocks * bt - n_tokens
-        if self.spec is not None and pad:
-            import jax.numpy as jnp
-            pads = [(0, 0)] * kv_k.ndim
-            pads[2] = (0, pad)
-            kv_k = jnp.pad(kv_k, pads)
-            kv_v = jnp.pad(kv_v, pads)
-        bids = self.alloc(seq_id, n_blocks, kind=kind)
-        for i, bid in enumerate(bids):
-            if self.spec is not None:
-                self.write_block(bid, kv_k[:, :, i * bt:(i + 1) * bt],
-                                 kv_v[:, :, i * bt:(i + 1) * bt])
+        with annotate("kv.write_prefill", blocks=n_blocks):
+            pad = n_blocks * bt - n_tokens
+            if self.spec is not None and pad:
+                import jax.numpy as jnp
+                pads = [(0, 0)] * kv_k.ndim
+                pads[2] = (0, pad)
+                kv_k = jnp.pad(kv_k, pads)
+                kv_v = jnp.pad(kv_v, pads)
+            bids = self.alloc(seq_id, n_blocks, kind=kind)
+            for i, bid in enumerate(bids):
+                if self.spec is not None:
+                    self.write_block(bid, kv_k[:, :, i * bt:(i + 1) * bt],
+                                     kv_v[:, :, i * bt:(i + 1) * bt])
         self.seq_len[seq_id] = n_tokens
         self._emit(seq_id, write_bytes=n_blocks * self.block_nbytes(),
                    phase="prefill")
@@ -354,6 +383,10 @@ class PagedKVPool:
         k_tok/v_tok: (U, n_attn, KV, hd).  The caller must have allocated
         a tail block when ``seq_len % block_tokens == 0``.
         """
+        with annotate("kv.append"):
+            self._append_token(seq_id, k_tok, v_tok)
+
+    def _append_token(self, seq_id: int, k_tok, v_tok) -> None:
         n = self.seq_len[seq_id]
         tbl = self.table[seq_id]
         blk_idx, off = divmod(n, self.block_tokens)
@@ -379,11 +412,13 @@ class PagedKVPool:
                 # runs on the device and the block goes back to its kind
                 k = jax.device_put(b.k, fast)
                 v = jax.device_put(b.v, fast)
+                self._count_transfer(b.kind, FAST_KIND)
             k = k.at[:, :, off].set(k_tok.astype(k.dtype))
             v = v.at[:, :, off].set(v_tok.astype(v.dtype))
             sh = self._sharding(b.kind)
             b.k = jax.device_put(k, sh)
             b.v = jax.device_put(v, sh)
+            self._count_transfer(FAST_KIND, b.kind)
         self.seq_len[seq_id] = n + 1
         self._emit(seq_id,
                    write_bytes=max(self.block_nbytes()
@@ -398,11 +433,15 @@ class PagedKVPool:
         host->device DMA of later blocks overlaps earlier concat work —
         the TieredArray.gather discipline.
         """
+        assert self.spec is not None, "gather_seq needs a data-mode pool"
+        tbl = self.table.get(seq_id, [])
+        with annotate("kv.gather_seq", rid=seq_id, blocks=len(tbl)):
+            return self._gather_seq(tbl, seq_id, pad_blocks)
+
+    def _gather_seq(self, tbl: List[int], seq_id: int, pad_blocks: int):
         import jax
         import jax.numpy as jnp
-        assert self.spec is not None, "gather_seq needs a data-mode pool"
         dev = self._sharding(FAST_KIND)
-        tbl = self.table.get(seq_id, [])
         if self.pooled:
             # staging copy out of the pooled stores (the baseline the
             # fused path's gather_tables exists to avoid): take the
@@ -444,6 +483,7 @@ class PagedKVPool:
             else:
                 ks.append(jax.device_put(b.k, dev))
                 vs.append(jax.device_put(b.v, dev))
+                self._count_transfer(b.kind, FAST_KIND)
         n_pad = pad_blocks - len(tbl)
         if n_pad < 0:
             raise ValueError(f"seq {seq_id} has {len(tbl)} blocks "
@@ -507,15 +547,17 @@ class PagedKVPool:
             self.counters.demoted += 1
         self.ledger.record_move(self.tenant, self._obj(b.seq_id),
                                 b.kind, kind, bn)
-        b.kind = kind
+        src, b.kind = b.kind, kind
         self.counters.migrated_bytes += bn
         # pooled layout keeps payloads in place in device memory: its
         # residency is ledger bookkeeping only
         if self.spec is not None and not self.pooled and b.k is not None:
             import jax
-            sh = self._sharding(kind)
-            b.k = jax.device_put(b.k, sh)
-            b.v = jax.device_put(b.v, sh)
+            with annotate("kv.migrate"):
+                sh = self._sharding(kind)
+                b.k = jax.device_put(b.k, sh)
+                b.v = jax.device_put(b.v, sh)
+                self._count_transfer(src, kind)
         return True
 
     # ------------------------------------------------------------------ #

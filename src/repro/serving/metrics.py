@@ -56,15 +56,6 @@ class RequestMetrics:
         return (self.new_tokens - 1) / max(span, 1e-9)
 
 
-@dataclasses.dataclass
-class PoolSample:
-    step: int
-    used_blocks: int
-    fast_blocks: int
-    running: int
-    waiting: int
-
-
 class ServingMetrics:
     """Aggregates request lifecycles, pool occupancy, and migration.
 
@@ -78,12 +69,12 @@ class ServingMetrics:
     def __init__(self, registry=None, slo=None,
                  max_decode_gaps: int = 65536):
         self.requests: Dict[int, RequestMetrics] = {}
-        self.samples: List[PoolSample] = []
         # retained inter-token gaps: exact tail quantiles (p95/p99)
         # over a bounded window — the QoS plane's victim-tail metric
         self.decode_gaps: Deque[float] = deque(
             maxlen=int(max_decode_gaps))
         self.iterations = 0
+        self.used_blocks_sum = 0      # blocks in use, summed over iterations
         self.prefills = 0
         self.decode_steps = 0
         self.decode_tokens = 0
@@ -155,13 +146,11 @@ class ServingMetrics:
                     "serving.latency_s",
                     help="end-to-end request latency").observe(r.latency_s)
 
-    def on_iteration(self, step: int, used_blocks: int, fast_blocks: int,
-                     running: int, waiting: int) -> None:
+    def on_iteration(self, used_blocks: int, running: int) -> None:
         self.iterations += 1
+        self.used_blocks_sum += used_blocks
         if running:
             self.decode_steps += 1
-        self.samples.append(PoolSample(step, used_blocks, fast_blocks,
-                                       running, waiting))
 
     # ------------------------------------------------------------------ #
     def aggregate_decode_tok_s(self) -> float:
@@ -171,9 +160,10 @@ class ServingMetrics:
         return self.decode_tokens / max(self.end_s - self.start_s, 1e-9)
 
     def mean_occupancy(self) -> float:
-        if not self.samples:
+        """Pool blocks in use, averaged over the iterations."""
+        if not self.iterations:
             return 0.0
-        return sum(s.used_blocks for s in self.samples) / len(self.samples)
+        return self.used_blocks_sum / self.iterations
 
     def summary(self, tiering: Optional[Dict[str, int]] = None
                 ) -> Dict[str, float]:
