@@ -91,10 +91,9 @@ class DecodeProbe:
             pool = self.engine.pool
             by_kind = {}
             for b in pool.blocks:
-                if b.k is not None:
-                    kind = b.k.sharding.memory_kind
-                    by_kind[kind] = (by_kind.get(kind, 0) + b.k.nbytes
-                                     + b.v.nbytes)
+                if b.kv is not None:
+                    kind = b.kv.sharding.memory_kind
+                    by_kind[kind] = by_kind.get(kind, 0) + b.kv.nbytes
             text = self.step.lower(params, tokens, kv_k, kv_v,
                                    lengths).as_text()
             self.seen = {

@@ -101,7 +101,7 @@ class Replica:
         own = set(self.mesh.devices.flat)
         pool = self.engine.pool
         arrays = jax.tree.leaves(self.params) + [
-            a for b in pool.blocks for a in (b.k, b.v) if a is not None]
+            b.kv for b in pool.blocks if b.kv is not None]
         arrays += [a for a in (pool.k_store, pool.v_store)
                    if a is not None]
         for a in arrays:

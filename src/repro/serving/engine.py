@@ -16,9 +16,10 @@ The serving loop puts spans on the profiler's clock
 ``serve.*`` spans around its phases, carrying the cumulative token and
 KV transfer counts (``_counters``) as args; the pool adds ``kv.*``
 spans.  The jitted programs are named ``serve_prefill``,
-``serve_decode`` and ``serve_decode_fused``, and the decode program's
-ops fall under the scopes ``attention``, ``kv_write``, ``mlp`` and
-``head``.
+``serve_decode``, ``serve_decode_fused`` and ``serve_kv_stage`` (the
+staged gather's layout of K and V for the decode step), and the decode
+program's ops fall under the scopes ``attention``, ``kv_write``, ``mlp``
+and ``head``.
 
 Supported configs: attention-only patterns (optionally MoE) with
 rope/learned/none positions and bf16 KV — the serving family of the
@@ -255,6 +256,15 @@ def _fused_paged_decode(cfg: ModelConfig, block_tokens: int, params,
     W = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = (x[:, 0] @ W.T).astype(jnp.float32)
     return logits, new_k, new_v, routed
+
+
+def _stage_kv(kvs):
+    """The batch's per-sequence (2, U, n_attn, S, KV, hd) payloads as
+    the decode step's K and V, (U, n_attn, B, S, KV, hd) each, each
+    written in one pass (stacking K and V apart needs no temporary
+    copy of the batch)."""
+    return (jnp.stack([kv[0] for kv in kvs], axis=2),
+            jnp.stack([kv[1] for kv in kvs], axis=2))
 
 
 def _program(fn: Callable, name: str):
@@ -662,6 +672,7 @@ class ServingEngine:
                                  "serve_prefill")
         self._decode = _program(functools.partial(_paged_decode, cfg, bt),
                                 "serve_decode")
+        self._kv_stage = _program(_stage_kv, "serve_kv_stage")
         self._decode_fused = (
             _program(functools.partial(_fused_paged_decode, cfg, bt),
                      "serve_decode_fused")
@@ -731,7 +742,8 @@ class ServingEngine:
         return dict(tokens_out=self.metrics.decode_tokens,
                     prefill_tokens=self._prefill_tokens,
                     kv_h2d_bytes=c.h2d_bytes, kv_d2h_bytes=c.d2h_bytes,
-                    kv_h2d_puts=c.h2d_puts, kv_d2h_puts=c.d2h_puts)
+                    kv_h2d_puts=c.h2d_puts, kv_d2h_puts=c.d2h_puts,
+                    kv_h2d_calls=c.h2d_calls, kv_d2h_calls=c.d2h_calls)
 
     def _do_prefill(self, req: Request, now: float) -> None:
         toks = req.prefill_tokens()[None]          # (1, L)
@@ -781,8 +793,10 @@ class ServingEngine:
         fixed batch shape (one compile).  Fused tiered-gather decode:
         the pooled stores and the batch's block-index tables, which the
         jitted step reads through — no staging copy.  Staged decode:
-        each sequence's blocks gathered onto the device and stacked,
-        (U, n_attn, B, S_pad, KV, hd) for K and for V."""
+        each sequence's blocks gathered onto the device, one
+        (2, U, n_attn, S_pad, KV, hd) buffer a sequence, laid out by
+        ``serve_kv_stage`` as (U, n_attn, B, S_pad, KV, hd) for K and
+        for V."""
         n_pad = self.max_batch - len(batch)
         if self._decode_fused is not None:
             tbl, _ = self.pool.gather_tables([r.rid for r in batch],
@@ -791,16 +805,11 @@ class ServingEngine:
                 tbl = np.concatenate(
                     [tbl, np.zeros((n_pad, tbl.shape[1]), np.int32)])
             return self.pool.k_store, self.pool.v_store, tbl
-        kv_ks, kv_vs = [], []
-        for req in batch:
-            k, v = self.pool.gather_seq(req.rid, self.max_seq_blocks)
-            kv_ks.append(k)
-            kv_vs.append(v)
+        kvs = [self.pool.gather_seq(req.rid, self.max_seq_blocks)
+               for req in batch]
         if n_pad:
-            z = jnp.zeros_like(kv_ks[0], device=kv_ks[0].sharding)
-            kv_ks.extend([z] * n_pad)
-            kv_vs.extend([z] * n_pad)
-        return jnp.stack(kv_ks, axis=2), jnp.stack(kv_vs, axis=2)
+            kvs += [jnp.zeros_like(kvs[0], device=kvs[0].sharding)] * n_pad
+        return self._kv_stage(kvs)
 
     def _record_routing(self, n_seqs: int, routed) -> None:
         """Routed expert ids (U, n_moe, B, K) feed per-expert heat."""

@@ -7,7 +7,8 @@ request's blocks go cold the moment the request finishes or is
 preempted).  The pool therefore manages fixed-size token blocks:
 
   * a block holds ``block_tokens`` tokens of K and V for every attention
-    layer of the model: k/v each ``(U, n_attn, block_tokens, KV, hd)``;
+    layer of the model, in one buffer ``kv`` of shape
+    ``(2, U, n_attn, block_tokens, KV, hd)``: K at index 0, V at 1;
   * each block is resident in one JAX memory kind ("device" = HBM
     analogue, "pinned_host"/"unpinned_host" = the CXL-class capacity
     tiers), moved with ``migrate`` — the mechanism tiering.py drives;
@@ -31,10 +32,12 @@ trace-driven scheduler benchmark and the pure-logic tests use.
 
 Data mode has two layouts:
 
-  * **per-block** (default): each block owns its own (k, v) arrays,
+  * **per-block** (default): each block owns its own payload array,
     ``device_put`` onto the block's memory kind — migration moves the
     payload.  ``gather_seq`` stages a sequence into one contiguous
-    buffer (the gather-then-compute path).
+    buffer (the gather-then-compute path): its host-resident blocks
+    cross to the device in one runtime call, its device-resident
+    blocks are used as they are.
   * **pooled** (``pooled=True``): payloads live in two persistent
     per-layer stores ``(U, n_attn, num_blocks, bt, KV, hd)`` indexed by
     physical block id.  This is the layout the fused tiered-gather
@@ -43,10 +46,11 @@ Data mode has two layouts:
     in device memory, so a block's tier in this layout is the ledger's
     bookkeeping only: no byte moves.
 
-Every ``device_put`` that carries a block between a host memory kind
-and the device is counted in ``PoolCounters`` (bytes and puts, each
-direction), and each method that moves payloads opens a ``kv.*`` span
-on the profiler's clock (``obs.trace.annotate``).
+A block's payload crosses between a host memory kind and the device
+as one buffer, and each such crossing is counted in ``PoolCounters``:
+bytes and blocks put, and the runtime calls that carried them, each
+direction.  Each method that moves payloads opens a ``kv.*`` span on
+the profiler's clock (``obs.trace.annotate``).
 """
 from __future__ import annotations
 
@@ -54,7 +58,10 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..obs.trace import annotate
 
@@ -78,11 +85,14 @@ class KVBlockSpec:
                 self.head_dim)
 
     @property
+    def payload_shape(self) -> Tuple[int, ...]:
+        """A block's K and V in one buffer: K at index 0, V at 1."""
+        return (2,) + self.kv_shape
+
+    @property
     def nbytes(self) -> int:
-        # K and V
-        import jax.numpy as jnp
         item = jnp.dtype(self.dtype).itemsize
-        return 2 * int(np.prod(self.kv_shape)) * item
+        return int(np.prod(self.payload_shape)) * item
 
 
 @dataclasses.dataclass
@@ -93,14 +103,20 @@ class KVBlock:
     kind: str                      # current memory kind
     seq_id: Optional[int] = None   # owner sequence (None = free)
     logical_idx: int = -1          # position in the owner's block table
-    k: Optional[object] = None     # jax.Array (U, n_attn, bt, KV, hd)
-    v: Optional[object] = None
+    kv: Optional[object] = None    # jax.Array, spec.payload_shape
     touch_count: int = 0
     last_touch_step: int = -(10 ** 9)
 
     @property
     def free(self) -> bool:
         return self.seq_id is None
+
+    @property
+    def k(self) -> Optional[object]:
+        """The whole payload ``kv`` (K and V), read-only; ``None`` until
+        the block is written.  It never slices out K: on a host memory
+        kind a slice would compute on host memory."""
+        return self.kv
 
 
 class PoolExhausted(Exception):
@@ -116,11 +132,40 @@ class PoolCounters:
     migrated_bytes: int = 0
     defrags: int = 0
     # payload transfers between a host memory kind and the device
-    # (PCIe on a TPU host); a block's (k, v) is two puts
+    # (PCIe on a TPU host): bytes, blocks put (one buffer a block), and
+    # the runtime calls that moved at least one block
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     h2d_puts: int = 0
     d2h_puts: int = 0
+    h2d_calls: int = 0
+    d2h_calls: int = 0
+
+
+def _stack_padded(kv_k, kv_v, pad):
+    """A prefill's K and V, (U, n_attn, n, KV, hd) each, as one
+    (2, U, n_attn, n + pad, KV, hd) buffer zero-padded on the token
+    axis."""
+    kv = jnp.stack([kv_k, kv_v])
+    return jnp.pad(kv, [(0, 0)] * 3 + [(0, pad)] + [(0, 0)] * 2)
+
+
+def _concat_blocks(parts):
+    """One sequence's block payloads in logical order, joined along the
+    token axis: (2, U, n_attn, len(parts) * bt, KV, hd)."""
+    return jnp.concatenate(parts, axis=3)
+
+
+def _write_token(kv, k_tok, v_tok, off):
+    """``kv`` with one token's K and V, (U, n_attn, KV, hd) each, at
+    token offset ``off``; ``kv`` is donated, so the update is in place."""
+    tok = jnp.stack([k_tok, v_tok]).astype(kv.dtype)
+    return lax.dynamic_update_index_in_dim(kv, tok, off, axis=3)
+
+
+_stack_padded = jax.jit(_stack_padded, static_argnums=2)
+_concat_blocks = jax.jit(_concat_blocks)
+_write_token = jax.jit(_write_token, donate_argnums=0)
 
 
 class PagedKVPool:
@@ -153,9 +198,10 @@ class PagedKVPool:
         # of the process-default device, so block arrays and the
         # replica's sharded params share one device set under jit
         self.sharding_fn = sharding_fn
+        self._shardings: Dict[str, object] = {}
+        self._zero = None          # shared zero block for the gather
         self.k_store = self.v_store = None
         if pooled:
-            import jax.numpy as jnp
             shape = (spec.n_units, spec.n_attn, num_blocks,
                      block_tokens, spec.n_kv, spec.head_dim)
             fast = self._sharding(FAST_KIND)
@@ -286,7 +332,7 @@ class PagedKVPool:
             b = self.blocks[bid]
             b.seq_id = None
             b.logical_idx = -1
-            b.k = b.v = None
+            b.kv = None
             self._free.append(bid)
             self.counters.frees += 1
         if tbl:
@@ -312,70 +358,81 @@ class PagedKVPool:
     # ------------------------------------------------------------------ #
     # payload I/O (data mode)                                            #
     # ------------------------------------------------------------------ #
-    def _count_transfer(self, src: str, dst: str) -> None:
-        """Count one block's (k, v) put from kind ``src`` to ``dst``
-        when it crosses between a host kind and the device."""
-        if (src == FAST_KIND) == (dst == FAST_KIND):
-            return
-        c, bn = self.counters, self.block_nbytes()
-        if dst == FAST_KIND:
-            c.h2d_bytes += bn
-            c.h2d_puts += 2
-        else:
-            c.d2h_bytes += bn
-            c.d2h_puts += 2
-
     def _sharding(self, kind: str):
-        if self.sharding_fn is not None:
-            return self.sharding_fn(kind)
-        from ..core.tiered_array import sharding_for_kind
-        return sharding_for_kind(kind)
+        sh = self._shardings.get(kind)
+        if sh is None:
+            if self.sharding_fn is not None:
+                sh = self.sharding_fn(kind)
+            else:
+                from ..core.tiered_array import sharding_for_kind
+                sh = sharding_for_kind(kind)
+            self._shardings[kind] = sh
+        return sh
 
-    def write_block(self, bid: int, k, v) -> None:
-        """Place (k, v) payloads, which are on the device (a prefill's
-        cache), on the block's current kind."""
-        if self.spec is None:
-            return
-        if self.pooled:
-            # pooled layout: payloads live at the block's slot in the
-            # persistent stores; residency is the ledger's (logical)
-            self.k_store = self.k_store.at[:, :, bid].set(
-                k.astype(self.k_store.dtype))
-            self.v_store = self.v_store.at[:, :, bid].set(
-                v.astype(self.v_store.dtype))
-            return
-        import jax
-        b = self.blocks[bid]
-        sh = self._sharding(b.kind)
-        b.k = jax.device_put(k, sh)
-        b.v = jax.device_put(v, sh)
-        self._count_transfer(FAST_KIND, b.kind)
+    def _put(self, payloads: Sequence, srcs: Sequence[str],
+             dsts: Sequence[str]) -> list:
+        """Place each payload on its kind in ``dsts`` in one runtime
+        call; count the blocks, and the call, that cross between a host
+        kind and the device (``srcs``: where each payload is now)."""
+        out = jax.device_put(list(payloads),
+                             [self._sharding(d) for d in dsts])
+        c, bn = self.counters, self.block_nbytes()
+        h2d = sum(s != FAST_KIND and d == FAST_KIND
+                  for s, d in zip(srcs, dsts))
+        d2h = sum(s == FAST_KIND and d != FAST_KIND
+                  for s, d in zip(srcs, dsts))
+        c.h2d_bytes += h2d * bn
+        c.h2d_puts += h2d
+        c.h2d_calls += h2d > 0
+        c.d2h_bytes += d2h * bn
+        c.d2h_puts += d2h
+        c.d2h_calls += d2h > 0
+        return out
+
+    def _zero_block(self):
+        if self._zero is None:
+            self._zero = jnp.zeros(self.spec.payload_shape,
+                                   dtype=self.spec.dtype,
+                                   device=self._sharding(FAST_KIND))
+        return self._zero
 
     def write_prefill(self, seq_id: int, kv_k, kv_v, n_tokens: int,
                       kind: Optional[str] = None) -> None:
         """Split a contiguous prefill cache into this sequence's blocks.
 
-        kv_k/kv_v: (U, n_attn, n_tokens, KV, hd) — batch already squeezed.
-        Allocates exactly the blocks the tokens need, on ``kind``.
+        kv_k/kv_v: (U, n_attn, n_tokens, KV, hd) on the device — batch
+        already squeezed.  Allocates exactly the blocks the tokens need,
+        on ``kind``, and places their payloads there in one call.
         """
         bt = self.block_tokens
         n_blocks = self.blocks_for_tokens(n_tokens)
         with annotate("kv.write_prefill", blocks=n_blocks):
-            pad = n_blocks * bt - n_tokens
-            if self.spec is not None and pad:
-                import jax.numpy as jnp
-                pads = [(0, 0)] * kv_k.ndim
-                pads[2] = (0, pad)
-                kv_k = jnp.pad(kv_k, pads)
-                kv_v = jnp.pad(kv_v, pads)
             bids = self.alloc(seq_id, n_blocks, kind=kind)
-            for i, bid in enumerate(bids):
-                if self.spec is not None:
-                    self.write_block(bid, kv_k[:, :, i * bt:(i + 1) * bt],
-                                     kv_v[:, :, i * bt:(i + 1) * bt])
+            if self.spec is not None:
+                kv = _stack_padded(kv_k, kv_v, n_blocks * bt - n_tokens)
+                self._write_blocks(bids, [kv[:, :, :, i * bt:(i + 1) * bt]
+                                          for i in range(n_blocks)])
         self.seq_len[seq_id] = n_tokens
         self._emit(seq_id, write_bytes=n_blocks * self.block_nbytes(),
                    phase="prefill")
+
+    def _write_blocks(self, bids: Sequence[int], payloads: Sequence
+                      ) -> None:
+        """Place payloads, which are on the device, on their blocks."""
+        if self.pooled:
+            # pooled layout: payloads live at the block's slot in the
+            # persistent stores; residency is the ledger's (logical)
+            for bid, kv in zip(bids, payloads):
+                self.k_store = self.k_store.at[:, :, bid].set(
+                    kv[0].astype(self.k_store.dtype))
+                self.v_store = self.v_store.at[:, :, bid].set(
+                    kv[1].astype(self.v_store.dtype))
+            return
+        blocks = [self.blocks[bid] for bid in bids]
+        placed = self._put(payloads, [FAST_KIND] * len(blocks),
+                           [b.kind for b in blocks])
+        for b, kv in zip(blocks, placed):
+            b.kv = kv
 
     def append_token(self, seq_id: int, k_tok, v_tok) -> None:
         """Write one new token's (k, v) at the tail of the sequence.
@@ -400,25 +457,21 @@ class PagedKVPool:
             self.v_store = self.v_store.at[:, :, bid, off].set(
                 v_tok.astype(self.v_store.dtype))
         elif self.spec is not None:
-            import jax
-            import jax.numpy as jnp
             b = self.blocks[tbl[blk_idx]]
-            fast = self._sharding(FAST_KIND)
-            if b.k is None:            # fresh tail block
-                k = v = jnp.zeros(self.spec.kv_shape, dtype=self.spec.dtype,
-                                  device=fast)
+            # host memory kinds hold data, not compute: the update runs
+            # on the device and the block goes back to its kind
+            if b.kv is None:           # fresh tail block
+                kv = jnp.zeros(self.spec.payload_shape,
+                               dtype=self.spec.dtype,
+                               device=self._sharding(FAST_KIND))
+            elif b.kind == FAST_KIND:
+                kv = b.kv
             else:
-                # host memory kinds hold data, not compute: the update
-                # runs on the device and the block goes back to its kind
-                k = jax.device_put(b.k, fast)
-                v = jax.device_put(b.v, fast)
-                self._count_transfer(b.kind, FAST_KIND)
-            k = k.at[:, :, off].set(k_tok.astype(k.dtype))
-            v = v.at[:, :, off].set(v_tok.astype(v.dtype))
-            sh = self._sharding(b.kind)
-            b.k = jax.device_put(k, sh)
-            b.v = jax.device_put(v, sh)
-            self._count_transfer(FAST_KIND, b.kind)
+                (kv,) = self._put([b.kv], [b.kind], [FAST_KIND])
+            kv = _write_token(kv, k_tok, v_tok, np.int32(off))
+            if b.kind != FAST_KIND:
+                (kv,) = self._put([kv], [FAST_KIND], [b.kind])
+            b.kv = kv
         self.seq_len[seq_id] = n + 1
         self._emit(seq_id,
                    write_bytes=max(self.block_nbytes()
@@ -426,12 +479,11 @@ class PagedKVPool:
                    phase="decode")
 
     def gather_seq(self, seq_id: int, pad_blocks: int):
-        """Contiguous (k, v) on the fast kind, padded to ``pad_blocks``.
-
-        Returns (k, v) of shape (U, n_attn, pad_blocks*bt, KV, hd).  All
-        block transfers are dispatched first (device_put is async) so
-        host->device DMA of later blocks overlaps earlier concat work —
-        the TieredArray.gather discipline.
+        """One contiguous payload on the fast kind, padded to
+        ``pad_blocks``: shape (2, U, n_attn, pad_blocks*bt, KV, hd), K at
+        index 0 and V at 1.  The sequence's host-resident blocks move in
+        one call (``device_put`` of the list, which is async), so every
+        block's transfer is issued before the concatenate waits on any.
         """
         assert self.spec is not None, "gather_seq needs a data-mode pool"
         tbl = self.table.get(seq_id, [])
@@ -439,25 +491,21 @@ class PagedKVPool:
             return self._gather_seq(tbl, seq_id, pad_blocks)
 
     def _gather_seq(self, tbl: List[int], seq_id: int, pad_blocks: int):
-        import jax
-        import jax.numpy as jnp
-        dev = self._sharding(FAST_KIND)
+        n_pad = pad_blocks - len(tbl)
+        if n_pad < 0:
+            raise ValueError(f"seq {seq_id} has {len(tbl)} blocks "
+                             f"> pad_blocks={pad_blocks}")
         if self.pooled:
             # staging copy out of the pooled stores (the baseline the
             # fused path's gather_tables exists to avoid): take the
             # sequence's blocks, flatten to token order, zero-pad.
             # Positions past seq_len may hold a prior owner's stale
             # tokens — every consumer masks by kv_len.
-            n_pad = pad_blocks - len(tbl)
-            if n_pad < 0:
-                raise ValueError(f"seq {seq_id} has {len(tbl)} blocks "
-                                 f"> pad_blocks={pad_blocks}")
-            shape = list(self.spec.kv_shape)
-            shape[2] = pad_blocks * self.block_tokens
+            shape = list(self.spec.payload_shape)
+            shape[3] = pad_blocks * self.block_tokens
             if not tbl:
-                z = jnp.zeros(tuple(shape), dtype=self.spec.dtype,
-                              device=dev)
-                return z, z
+                return jnp.zeros(tuple(shape), dtype=self.spec.dtype,
+                                 device=self._sharding(FAST_KIND))
             idx = np.asarray(tbl, np.int32)
 
             def take(store):
@@ -469,36 +517,21 @@ class PagedKVPool:
                     g = jnp.pad(g, pads)
                 return g
 
-            return take(self.k_store), take(self.v_store)
-        zero = None
-        ks, vs = [], []
-        for bid in tbl:
-            b = self.blocks[bid]
-            if b.k is None:            # allocated tail block, not written
-                if zero is None:
-                    zero = jnp.zeros(self.spec.kv_shape,
-                                     dtype=self.spec.dtype, device=dev)
-                ks.append(zero)
-                vs.append(zero)
-            else:
-                ks.append(jax.device_put(b.k, dev))
-                vs.append(jax.device_put(b.v, dev))
-                self._count_transfer(b.kind, FAST_KIND)
-        n_pad = pad_blocks - len(tbl)
-        if n_pad < 0:
-            raise ValueError(f"seq {seq_id} has {len(tbl)} blocks "
-                             f"> pad_blocks={pad_blocks}")
-        if n_pad:
-            z = jnp.zeros(self.spec.kv_shape, dtype=self.spec.dtype,
-                          device=dev)
-            ks.extend([z] * n_pad)
-            vs.extend([z] * n_pad)
-        if not ks:
-            shape = list(self.spec.kv_shape)
-            shape[2] = pad_blocks * self.block_tokens
-            z = jnp.zeros(tuple(shape), dtype=self.spec.dtype, device=dev)
-            return z, z
-        return jnp.concatenate(ks, axis=2), jnp.concatenate(vs, axis=2)
+            return jnp.stack([take(self.k_store), take(self.v_store)])
+        blocks = [self.blocks[bid] for bid in tbl]
+        host = [b for b in blocks
+                if b.kv is not None and b.kind != FAST_KIND]
+        moved = dict(zip([b.bid for b in host],
+                         self._put([b.kv for b in host],
+                                   [b.kind for b in host],
+                                   [FAST_KIND] * len(host))))
+        # a tail block allocated but not yet written reads as zeros;
+        # the pad is the same shared zero block, so the concatenate
+        # always takes pad_blocks operands and compiles once
+        zero = self._zero_block()
+        parts = [zero if b.kv is None else moved.get(b.bid, b.kv)
+                 for b in blocks] + [zero] * n_pad
+        return _concat_blocks(parts)
 
     def gather_tables(self, seq_ids: Sequence[int], pad_blocks: int
                       ) -> Tuple[np.ndarray, np.ndarray]:
@@ -551,13 +584,9 @@ class PagedKVPool:
         self.counters.migrated_bytes += bn
         # pooled layout keeps payloads in place in device memory: its
         # residency is ledger bookkeeping only
-        if self.spec is not None and not self.pooled and b.k is not None:
-            import jax
+        if self.spec is not None and not self.pooled and b.kv is not None:
             with annotate("kv.migrate"):
-                sh = self._sharding(kind)
-                b.k = jax.device_put(b.k, sh)
-                b.v = jax.device_put(b.v, sh)
-                self._count_transfer(src, kind)
+                (b.kv,) = self._put([b.kv], [src], [kind])
         return True
 
     # ------------------------------------------------------------------ #
@@ -586,14 +615,13 @@ class PagedKVPool:
             nb.kind = old.kind
             nb.seq_id = old.seq_id
             nb.logical_idx = old.logical_idx
-            nb.k, nb.v = old.k, old.v
+            nb.kv = old.kv
             nb.touch_count = old.touch_count
             nb.last_touch_step = old.last_touch_step
             new_table[old.seq_id].append(i)
         if self.pooled and live:
             # permute the store rows with the block ids so slot i still
             # holds the payload of the block now labelled i
-            import jax.numpy as jnp
             perm = [old.bid for old in live]
             rest = [i for i in range(self.num_blocks)
                     if i not in set(perm)]

@@ -336,7 +336,7 @@ def test_replica_refuses_arrays_off_its_mesh(tiny):
     rep.check_placement()
     # a block array made with no device lands on chip 0
     blk = rep.engine.pool.blocks[0]
-    blk.k = blk.v = jax.numpy.zeros(rep.engine.pool.spec.kv_shape)
+    blk.kv = jax.numpy.zeros(rep.engine.pool.spec.payload_shape)
     with pytest.raises(RuntimeError, match="outside its mesh"):
         rep.check_placement()
 

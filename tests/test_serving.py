@@ -118,10 +118,9 @@ def test_gather_seq_requires_data_mode():
 @pytest.mark.parametrize("pooled", [False, True])
 def test_gather_seq_empty_sequence_is_zero_padded(pooled):
     pool, spec = _data_pool(pooled)
-    k, v = pool.gather_seq(99, 3)             # unknown seq: no blocks
-    assert k.shape == (1, 2, 3 * 4, 2, 8)
-    assert float(jnp.abs(k).sum()) == 0.0
-    assert float(jnp.abs(v).sum()) == 0.0
+    kv = pool.gather_seq(99, 3)               # unknown seq: no blocks
+    assert kv.shape == (2, 1, 2, 3 * 4, 2, 8)  # K at 0, V at 1
+    assert float(jnp.abs(kv).sum()) == 0.0
 
 
 @pytest.mark.parametrize("pooled", [False, True])
@@ -159,10 +158,11 @@ def test_append_token_keeps_block_in_its_memory_kind():
     pool.alloc(5, 1, kind="pinned_host")
     pool.append_token(5, tok, tok)              # a fresh tail block
     for b in pool.seq_blocks(5):
-        assert b.k.sharding.memory_kind == "pinned_host"
-        assert b.v.sharding.memory_kind == "pinned_host"
-    k, v = pool.gather_seq(5, 3)
-    assert k.sharding.memory_kind == "device"
+        assert b.kv.sharding.memory_kind == "pinned_host"
+        assert b.kv.shape == spec.payload_shape and b.k is b.kv
+    kv = pool.gather_seq(5, 3)
+    assert kv.sharding.memory_kind == "device"
+    k, v = kv
     np.testing.assert_array_equal(np.asarray(k[:, :, :3]),
                                   np.asarray(kv_k))
     np.testing.assert_array_equal(np.asarray(k[:, :, 3]), np.asarray(tok))
@@ -170,6 +170,145 @@ def test_append_token_keeps_block_in_its_memory_kind():
                                   np.asarray(-tok))
     np.testing.assert_array_equal(np.asarray(k[:, :, 4]), np.asarray(tok))
     assert float(jnp.abs(k[:, :, 5:]).sum()) == 0.0
+
+
+# one buffer a block, one call a sequence: the staged gather against a
+# per-block reference, and each path that moves a payload round-trips it
+MIXED = ["pinned_host", FAST_KIND, "unpinned_host", FAST_KIND]
+
+
+def _written_seq(pool, kinds, seq_id=3, seed=2):
+    """Prefill ``len(kinds)`` blocks of ``seq_id``, block i on kinds[i],
+    then allocate one tail block (on kinds[0]) that is not written.
+    Returns the prefill's K and V, (1, 2, n_tokens, 2, 8) each."""
+    rs = np.random.RandomState(seed)
+    n = len(kinds) * pool.block_tokens
+    kv_k = jnp.asarray(rs.randn(1, 2, n, 2, 8), jnp.float32)
+    kv_v = jnp.asarray(rs.randn(1, 2, n, 2, 8), jnp.float32)
+    it = iter(kinds)
+    pool.write_prefill(seq_id, kv_k, kv_v, n_tokens=n,
+                       kind=lambda: next(it))
+    pool.alloc(seq_id, 1, kind=kinds[0])
+    return kv_k, kv_v
+
+
+def _puts(pool):
+    c = pool.counters
+    return c.h2d_puts, c.h2d_calls, c.d2h_puts, c.d2h_calls
+
+
+@pytest.mark.parametrize("kinds", [MIXED, [FAST_KIND] * 4,
+                                   ["pinned_host"] * 4])
+def test_gather_seq_matches_a_per_block_reference(kinds):
+    pool, spec = _data_pool(num_blocks=8)
+    _written_seq(pool, kinds)
+    blocks = pool.seq_blocks(3)
+    assert [b.kind for b in blocks[:-1]] == kinds
+    assert blocks[-1].kv is None              # the unwritten tail
+    before = _puts(pool)
+    kv = pool.gather_seq(3, 7)                # 5 blocks + 2 pad
+    n_host = sum(k != FAST_KIND for k in kinds)
+    h2d, calls, d2h, d2h_calls = np.subtract(_puts(pool), before)
+    assert (h2d, calls) == (n_host, 1 if n_host else 0)
+    assert (d2h, d2h_calls) == (0, 0)
+    assert kv.sharding.memory_kind == FAST_KIND
+    zero = np.zeros(spec.payload_shape, np.float32)
+    ref = np.concatenate([np.asarray(b.kv) if b.kv is not None else zero
+                          for b in blocks] + [zero, zero], axis=3)
+    np.testing.assert_array_equal(np.asarray(kv), ref)
+    # the blocks stay where they were
+    assert [b.kv.sharding.memory_kind for b in blocks[:-1]] == kinds
+
+
+def test_gather_seq_moves_each_sequence_in_one_call():
+    pool, _ = _data_pool(num_blocks=16)
+    _written_seq(pool, MIXED, seq_id=1)
+    _written_seq(pool, [FAST_KIND] * 2, seq_id=2)
+    _written_seq(pool, ["pinned_host"] * 3, seq_id=4)
+    before = _puts(pool)
+    for sid in (1, 2, 4):
+        pool.gather_seq(sid, 6)
+    assert tuple(np.subtract(_puts(pool), before)) == (2 + 0 + 3, 2, 0, 0)
+
+
+@pytest.mark.parametrize("kinds", [MIXED, ["pinned_host"] * 4])
+def test_write_prefill_places_each_block_bitwise(kinds):
+    pool, spec = _data_pool(num_blocks=8)
+    kv_k, kv_v = _written_seq(pool, kinds)
+    n_host = sum(k != FAST_KIND for k in kinds)
+    assert _puts(pool) == (0, 0, n_host, 1 if n_host else 0)
+    want = np.stack([np.asarray(kv_k), np.asarray(kv_v)])
+    for i, b in enumerate(pool.seq_blocks(3)[:-1]):
+        assert b.kv.sharding.memory_kind == kinds[i]
+        np.testing.assert_array_equal(np.asarray(b.kv),
+                                      want[:, :, :, 4 * i:4 * (i + 1)])
+
+
+@pytest.mark.parametrize("kind", ["pinned_host", "unpinned_host",
+                                  FAST_KIND])
+def test_append_token_round_trips_the_payload(kind):
+    pool, spec = _data_pool(num_blocks=8)
+    rs = np.random.RandomState(3)
+    kv_k = jnp.asarray(rs.randn(1, 2, 6, 2, 8), jnp.float32)
+    kv_v = jnp.asarray(rs.randn(1, 2, 6, 2, 8), jnp.float32)
+    pool.write_prefill(1, kv_k, kv_v, n_tokens=6, kind=kind)
+    tail = pool.seq_blocks(1)[1]
+    want = np.array(tail.kv)
+    toks = [jnp.asarray(rs.randn(2, 1, 2, 2, 8), jnp.float32)
+            for _ in range(3)]
+    before = _puts(pool)
+    for t in toks[:2]:                          # into the written tail
+        pool.append_token(1, t[0], t[1])
+    want[:, :, :, 2:4] = np.stack([np.asarray(t) for t in toks[:2]],
+                                  axis=3)
+    pool.alloc(1, 1, kind=kind)
+    pool.append_token(1, toks[2][0], toks[2][1])  # a fresh tail block
+    host = kind != FAST_KIND
+    # in and out once a written block, out once the fresh one
+    assert tuple(np.subtract(_puts(pool), before)) == \
+        ((2, 2, 3, 3) if host else (0, 0, 0, 0))
+    np.testing.assert_array_equal(np.asarray(tail.kv), want)
+    fresh = pool.seq_blocks(1)[2]
+    assert fresh.kv.sharding.memory_kind == kind
+    assert tail.kv.sharding.memory_kind == kind
+    got = np.asarray(fresh.kv)
+    np.testing.assert_array_equal(got[:, :, :, 0], np.asarray(toks[2]))
+    assert not got[:, :, :, 1:].any()
+    assert pool.seq_len[1] == 9
+
+
+def test_migrate_round_trips_the_payload():
+    pool, _ = _data_pool(num_blocks=8)
+    _written_seq(pool, ["pinned_host"])
+    b = pool.seq_blocks(3)[0]
+    want = np.asarray(b.kv)
+    before = _puts(pool)
+    for kind in (FAST_KIND, "unpinned_host", FAST_KIND, "pinned_host"):
+        assert pool.migrate(b.bid, kind)
+        assert b.kv.sharding.memory_kind == kind
+        np.testing.assert_array_equal(np.asarray(b.kv), want)
+    assert tuple(np.subtract(_puts(pool), before)) == (2, 2, 2, 2)
+
+
+def test_defrag_keeps_each_payload_with_its_block():
+    pool, _ = _data_pool(num_blocks=12)
+    _written_seq(pool, ["pinned_host"] * 2, seq_id=1)
+    _written_seq(pool, MIXED, seq_id=2, seed=5)
+    pool.free_seq(1)
+    before = {(b.seq_id, b.logical_idx): (b.kind, np.asarray(b.kv))
+              for b in pool.blocks if b.kv is not None}
+    ref = np.asarray(pool.gather_seq(2, 6))
+    assert pool.defrag() > 0
+    assert pool.table[2] == list(range(5))
+    after = {(b.seq_id, b.logical_idx): (b.kind, np.asarray(b.kv))
+             for b in pool.blocks if b.kv is not None}
+    assert before.keys() == after.keys()
+    for key, (kind, kv) in before.items():
+        assert after[key][0] == kind
+        assert pool.blocks[pool.table[2][key[1]]].kv.sharding \
+            .memory_kind == kind
+        np.testing.assert_array_equal(after[key][1], kv)
+    np.testing.assert_array_equal(np.asarray(pool.gather_seq(2, 6)), ref)
 
 
 def test_gather_tables_requires_pooled_layout():

@@ -18,7 +18,8 @@ from repro.serving import (KVBlockSpec, PagedKVPool, ServingConfig,
 from repro.serving.metrics import ServingMetrics
 
 COUNTERS = ("tokens_out", "prefill_tokens", "kv_h2d_bytes",
-            "kv_d2h_bytes", "kv_h2d_puts", "kv_d2h_puts")
+            "kv_d2h_bytes", "kv_h2d_puts", "kv_d2h_puts", "kv_h2d_calls",
+            "kv_d2h_calls")
 PROGRAM_PREFIXES = ("serve.", "kv.", "tier.")
 # every span of the serving loop, and the span each one nests in
 PARENT = {
@@ -114,28 +115,30 @@ def _script(pool, kind):
     pool.migrate(first, kind)
     pool.gather_seq(1, 4)
     c = pool.counters
-    return c.h2d_bytes, c.d2h_bytes, c.h2d_puts, c.d2h_puts
+    return (c.h2d_bytes, c.d2h_bytes, c.h2d_puts, c.d2h_puts, c.h2d_calls,
+            c.d2h_calls)
 
 
 def test_pool_counts_every_host_device_crossing():
     pool = _pool()
     bn = pool.block_nbytes()
     assert bn == 2 * 1 * 2 * 4 * 2 * 8 * 4       # K and V, float32
-    h2d = (2          # first gather: both prefill blocks
+    # one put a block (K and V are one buffer), one call a gather
+    h2d = (2          # first gather: both prefill blocks, one call
            + 2        # two appends into the written tail block: in ...
            + 1        # migration to the device
-           + 3)       # second gather: all three blocks
-    d2h = (2          # prefill writes
+           + 3)       # second gather: all three blocks, one call
+    d2h = (2          # prefill writes, one call
            + 2        # ... and back out
            + 1        # append into a fresh block: out only
            + 1)       # migration back
-    assert _script(pool, "pinned_host") == (h2d * bn, d2h * bn, 2 * h2d,
-                                            2 * d2h)
+    assert _script(pool, "pinned_host") == (h2d * bn, d2h * bn, h2d, d2h,
+                                            1 + 2 + 1 + 1, 1 + 2 + 1 + 1)
 
 
 def test_pool_counts_nothing_on_the_device_or_in_the_pooled_layout():
-    assert _script(_pool(default_kind="device"), "device") == (0, 0, 0, 0)
-    assert _script(_pool(pooled=True), "pinned_host") == (0, 0, 0, 0)
+    assert _script(_pool(default_kind="device"), "device") == (0,) * 6
+    assert _script(_pool(pooled=True), "pinned_host") == (0,) * 6
 
 
 # ===================================================================== #
@@ -198,7 +201,7 @@ def test_engine_spans_carry_the_counters(served):
     assert final["tokens_out"] == 3 * 6
     assert final["prefill_tokens"] == 3 * 12
     assert final["kv_h2d_bytes"] > 0 and final["kv_d2h_bytes"] > 0
-    assert final["kv_h2d_puts"] == 2 * final["kv_h2d_bytes"] \
+    assert final["kv_h2d_puts"] == 1 * final["kv_h2d_bytes"] \
         // eng.pool.block_nbytes()
     iterations = [s for s in spans if s[0] == "serve.iteration"]
     for name in ("serve.iteration", "kv.gather", "serve.decode"):
@@ -216,6 +219,28 @@ def test_engine_spans_carry_the_counters(served):
     prefills = [s for s in spans if s[0] == "serve.prefill"]
     assert sorted(s[3]["rid"] for s in prefills) == [0, 1, 2]
     assert all(s[3]["tokens"] == 12 for s in prefills)
+
+
+def test_a_gather_moves_each_sequence_in_one_call(served):
+    """Between a ``kv.gather`` and its ``serve.decode``, one put a
+    host-resident block and at most one call a sequence."""
+    eng, spans = served
+    gathers = sorted((s for s in spans if s[0] == "kv.gather"),
+                     key=lambda s: s[1])
+    decodes = sorted((s for s in spans if s[0] == "serve.decode"),
+                     key=lambda s: s[1])
+    assert len(gathers) == len(decodes)
+    moved = 0
+    for g, d in zip(gathers, decodes):
+        assert g[2] <= d[1]
+        puts = d[3]["kv_h2d_puts"] - g[3]["kv_h2d_puts"]
+        calls = d[3]["kv_h2d_calls"] - g[3]["kv_h2d_calls"]
+        assert puts <= g[3]["blocks"] and calls <= g[3]["seqs"]
+        assert (calls > 0) == (puts > 0)
+        assert d[3]["kv_h2d_bytes"] - g[3]["kv_h2d_bytes"] == \
+            puts * eng.pool.block_nbytes()
+        moved += puts
+    assert moved > 0              # the pool holds 2 of 12 blocks in HBM
 
 
 def test_hot_path_spans_stay_out_of_the_recorder_ring(served):
