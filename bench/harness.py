@@ -2,35 +2,33 @@
 
 Everything a cell needs is found by name: the cell's entry in
 ``BENCHMARK.json`` names a configuration (``bench/configs/<name>.json``)
-and a traffic mix (``bench/traffic/<name>.json``); the cell's serving
-settings and check limits are ``bench/cells/<cell>.json``; each metric
-is read by ``bench/metrics/<metric>.py``.  Adding a configuration, a
+and a traffic mix (``bench/traffic/<name>.json``); the configuration
+names its family (``bench/families/<family>.py``, "dense" where it
+names none), which reads it and brings the architecture's weight rules,
+float32 reference and work counts; the cell's serving settings and
+check limits are ``bench/cells/<cell>.json``; each metric is read by
+``bench/metrics/<metric>.py``.  Adding a configuration, a family, a
 mix, a cell or a metric adds files and entries and edits none.
 """
 from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import sys
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 from bench import loadgen
-from bench.model import Arch
+from bench.lookup import (BENCH, BenchError, family_of, load_arch,
+                          load_reader)
 
-ROOT = Path(__file__).resolve().parents[1]
-BENCH = ROOT / "bench"
+ROOT = BENCH.parent
 TRACE_DIR = ROOT / ".bench_trace"
-
-
-class BenchError(RuntimeError):
-    """A run that cannot be measured: it prints no result."""
 
 
 # ---------------------------------------------------------------------- #
@@ -54,22 +52,11 @@ def metrics_for(bench: Dict, cell: str, trace: bool) -> List[Dict]:
     return [m for m in pool if cell in m.get("workloads", [cell])]
 
 
-def load_reader(name: str, root: Path = BENCH):
-    path = root / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise BenchError(f"metric {name!r} has no reader at {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @dataclasses.dataclass
 class Cell:
     name: str
     chips: int
-    arch: Arch
+    arch: Any               # made by its family's load()
     mix: loadgen.Mix
     serving: Dict
     check: Dict
@@ -79,7 +66,8 @@ class Cell:
         w = find_cell(bench, name)
         settings = json.loads((root / "cells" / f"{name}.json").read_text())
         return cls(name=name, chips=int(w["chips"]),
-                   arch=Arch.load(root / "configs" / f"{w['config']}.json"),
+                   arch=load_arch(root / "configs" / f"{w['config']}.json",
+                                  root),
                    mix=loadgen.Mix.load(root / "traffic"
                                         / f"{w['traffic']}.json"),
                    serving=settings["serving"], check=settings["check"])
@@ -126,7 +114,7 @@ def iteration_line(starts: List[float], meter: HostMeter) -> str:
 @dataclasses.dataclass
 class Run:
     """What the metric readers read."""
-    arch: Arch
+    arch: Any
     mix: loadgen.Mix
     peak: Dict
     setup_s: float
@@ -196,13 +184,13 @@ def pick_checked(records, max_requests: int, seed: int) -> list:
     return [served[0]] + [served[1 + i] for i in sorted(rest)]
 
 
-def reference_gaps(arch: Arch, params, mix: loadgen.Mix, checked,
+def reference_gaps(arch, params, mix: loadgen.Mix, checked,
                    mode: Optional[str] = None) -> List[np.ndarray]:
-    """Per checked request, the reference's gap at each served token:
-    how far the served token's logit lies below the reference's best.
-    With ``mode``, the gap of the token that that lower-precision
+    """Per checked request, the family's reference's gap at each served
+    token: how far the served token's logit lies below the reference's
+    best.  With ``mode``, the gap of the token that that lower-precision
     computation puts first instead."""
-    from bench import reference as ref
+    ref = family_of(arch)
     out = []
     for r in checked:
         L, n = len(r.prompt), len(r.tokens)
@@ -250,7 +238,7 @@ def run_cell(bench: Dict, name: str, seed: int, seconds: float,
     seed %= 1 << 64
     cfg = cell.arch.program_config()
     t0 = time.perf_counter()
-    params = weights.make_params(cfg, seed, dev)
+    params = weights.make_params(cfg, seed, dev, family_of(cell.arch).fill)
     jax.block_until_ready(params)
     t1 = time.perf_counter()
     engine = ServingEngine(cfg, params, serving_config(cell))

@@ -4,8 +4,10 @@ The benchmark makes the weights itself, so that the reference takes
 nothing the program made.  The tree's structure and dtypes come from
 the program's own initializer under ``jax.eval_shape`` (nothing is
 computed there); every leaf is then filled in one jitted call on the
-device, by a rule keyed on the leaf's name.  Biases and norm parameters
-are random too: zeros and ones would leave those paths unchecked.
+device, by the configuration's family's rule for it where the family
+has one, and otherwise by a shared rule keyed on the leaf's name.
+Biases and norm parameters are random too: zeros and ones would leave
+those paths unchecked.
 """
 from __future__ import annotations
 
@@ -33,7 +35,10 @@ def leaf_name(path) -> str:
     return str(getattr(path[-1], "key", getattr(path[-1], "idx", "")))
 
 
-def _fill(path, leaf: jax.ShapeDtypeStruct, key) -> jax.Array:
+def _fill(path, leaf: jax.ShapeDtypeStruct, key, family_fill) -> jax.Array:
+    x = family_fill(path, leaf, key)
+    if x is not None:
+        return x.astype(leaf.dtype)
     name = leaf_name(path)
     parent = leaf_name(path[:-1]) if len(path) > 1 else ""
     shape, dtype = leaf.shape, leaf.dtype
@@ -57,9 +62,11 @@ def _fill(path, leaf: jax.ShapeDtypeStruct, key) -> jax.Array:
     return x.astype(dtype)
 
 
-def make_params(cfg, seed: int, device):
+def make_params(cfg, seed: int, device, family_fill):
     """The program's parameter tree for ``cfg``, random from ``seed``,
-    made on ``device`` in one jitted call."""
+    made on ``device`` in one jitted call.  ``family_fill`` is the
+    family's ``fill``: a leaf's value, or ``None`` for the shared
+    rules."""
     from repro.models import lm
     key = seed_key(seed)
     shapes = jax.eval_shape(functools.partial(lm.init_params, cfg=cfg), key)
@@ -68,7 +75,7 @@ def make_params(cfg, seed: int, device):
     def build(k):
         keys = jax.random.split(k, len(paths))
         return jax.tree_util.tree_unflatten(
-            treedef, [_fill(p, leaf, keys[i])
+            treedef, [_fill(p, leaf, keys[i], family_fill)
                       for i, (p, leaf) in enumerate(paths)])
 
     return jax.jit(build, out_shardings=SingleDeviceSharding(device))(key)

@@ -4,20 +4,19 @@ from pathlib import Path
 
 import pytest
 
-from bench import flops
-from bench.model import Arch
+from bench import flops, lookup
 
 CONFIGS = Path(__file__).resolve().parents[2] / "bench" / "configs"
 
 
 @pytest.fixture(scope="module")
 def stablelm():
-    return Arch.load(CONFIGS / "stablelm-1.6b.json")
+    return lookup.load_arch(CONFIGS / "stablelm-1.6b.json")
 
 
 @pytest.fixture(scope="module")
 def codeqwen():
-    return Arch.load(CONFIGS / "codeqwen1.5-7b.json")
+    return lookup.load_arch(CONFIGS / "codeqwen1.5-7b.json")
 
 
 def test_stablelm_shapes(stablelm):

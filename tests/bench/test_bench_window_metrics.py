@@ -54,3 +54,20 @@ def test_setup_and_empty_windows():
     for name in ("output_tok_s", "kv_gather_ms_per_step",
                  "prefill_ms_p50.long", "kv_host_bytes_per_tok"):
         assert metric(name, empty) is None
+
+
+def test_itl_median_counts_gaps_wholly_inside_the_window():
+    run = timeline()
+    # 77 gaps of 0.1 s and the 2 s stall: the median is a plain gap
+    assert metric("itl_p50_ms", run) == pytest.approx(100.0)
+    run.records = [NS(times=[-1.0, 0.5, 0.8, 1.0, 10.2])]
+    # 300 and 200 ms lie inside; 1500 and 9200 ms cross an edge
+    assert metric("itl_p50_ms", run) == pytest.approx(250.0)
+    run.records = [NS(times=[0.5]), NS(times=[9.5, 10.5])]
+    assert metric("itl_p50_ms", run) is None
+
+
+def test_the_unbounded_rate_reads_as_the_end_to_end_one():
+    run = timeline()
+    assert metric("output_tok_s.unbounded", run) == \
+        metric("output_tok_s", run)
